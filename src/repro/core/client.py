@@ -137,6 +137,9 @@ class CommitHandle:
             except Exception:   # noqa: BLE001 - never mask the commit error
                 pass
         finally:
+            # the payloads are stored (or lost): a handle kept until
+            # finalize must not pin a checkpoint's bytes in host memory
+            self._puts = []
             self._done.set()
 
     def _await_with_straggler_retry(self, key: ShardKey, payload: bytes,

@@ -99,11 +99,12 @@ class HealthMonitor:
             if mgr is None:
                 return
         ctl.bus.publish(E.NODE_FAILED, node=node_id)
+        # what the node held, read before close() gives its memory back
+        lost: List[ShardKey] = mgr.store.keys()
         mgr.close()
         # erasure-coded stripes get a peer *rebuild* (a surviving agent
         # regenerates just the lost fragments from any k survivors); whole
         # shards are re-copied from surviving replicas/L2
-        lost: List[ShardKey] = mgr.store.keys()
         stripes: Dict[ShardKey, List[int]] = {}
         for key in lost:
             base = key.base()

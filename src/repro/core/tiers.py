@@ -793,6 +793,14 @@ class MemoryTier:
                         freed += len(payload)
         return freed
 
+    def close(self) -> None:
+        """Give the node's memory back: a closed agent holds no shards, even
+        while some stray reference keeps the tier object alive."""
+        with self._lock:
+            self._data.clear()
+            self._crc.clear()
+            self._used = 0
+
 
 # --------------------------------------------------------------------------
 # L0.5: node-local disk spill (burst-buffer analogue)

@@ -9,7 +9,9 @@ Each kernel package ships three paths (see ``common.resolve_impl``):
 Exports resolve lazily (PEP 562): importing :mod:`repro.kernels` (or a
 jax-free submodule such as ``ckpt_codec.blocks``, which the host-side wire
 codec in ``repro.core.tiers`` depends on) does not import jax until a
-kernel op is actually touched.
+kernel op is actually touched.  The ``rwkv6`` and ``rglru`` ops are not
+exported here: their names are those of their subpackages, which replace
+the attribute once imported, so import them from the subpackage.
 """
 from __future__ import annotations
 
@@ -17,8 +19,7 @@ from importlib import import_module
 
 _EXPORTS = {
     "attention": ".flash_attention", "attention_ref": ".flash_attention",
-    "rwkv6": ".rwkv6", "rwkv6_ref": ".rwkv6",
-    "rglru": ".rglru", "rglru_ref": ".rglru",
+    "rwkv6_ref": ".rwkv6", "rglru_ref": ".rglru",
     "quantize": ".ckpt_codec", "quantize_delta": ".ckpt_codec",
     "dequantize": ".ckpt_codec", "undelta_dequantize": ".ckpt_codec",
     "resolve_impl": ".common",

@@ -22,6 +22,9 @@ except Exception:  # pragma: no cover - optional
     pass
 
 BLOCK = 256  # values per quantization block (one f32 scale each)
+# clears the low 12 of a scale's 23 mantissa bits: the head that is left
+# times a code (7 bits) is exact in f32, and so is the tail times a code
+SCALE_HEAD_MASK = ~0xFFF
 
 
 def to_blocks_np(x: np.ndarray) -> Tuple[np.ndarray, int]:
@@ -34,18 +37,35 @@ def to_blocks_np(x: np.ndarray) -> Tuple[np.ndarray, int]:
     return blocks, n
 
 
+def round_to_codes_np(x: np.ndarray, scale: np.ndarray) -> np.ndarray:
+    """int8 codes nearest ``x / scale`` (f32), clipped to [-127, 127].
+
+    The f32 quotient alone is not enough: it can be an ulp off (a TPU's
+    divide is), and at a rounding midpoint that picks the farther integer,
+    an error past half a scale.  The residual ``x - q * scale``, computed
+    without rounding error (scale = head + tail, each times a code exact),
+    moves such a code one step back, so every code is within half a scale
+    of its value on any backend.
+    """
+    q = np.round(x / scale)
+    head = (scale.view(np.int32) & np.int32(SCALE_HEAD_MASK)).view(np.float32)
+    r = (x - q * head) - q * (scale - head)
+    half = np.float32(0.5) * scale
+    q = q + (r > half) - (r < -half)
+    return np.clip(q, -127, 127).astype(np.int8)
+
+
 def quantize_np(blocks: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     """(nb, BLOCK) f32 -> (int8 codes (nb, BLOCK), f32 scales (nb, 1)).
 
     The numpy mirror of ``ref.quantize_ref`` / the Pallas quantize kernel:
-    absmax/127 scale per block (1.0 for all-zero blocks), round-to-nearest,
-    clip to [-127, 127].
+    absmax/127 scale per block (1.0 for all-zero blocks), round to the
+    nearest code (:func:`round_to_codes_np`), clip to [-127, 127].
     """
     blocks = blocks.astype(np.float32, copy=False)
     absmax = np.max(np.abs(blocks), axis=-1, keepdims=True)
     scale = np.where(absmax > 0, absmax / 127.0, 1.0).astype(np.float32)
-    q = np.clip(np.round(blocks / scale), -127, 127).astype(np.int8)
-    return q, scale
+    return round_to_codes_np(blocks, scale), scale
 
 
 def dequantize_np(q: np.ndarray, scale: np.ndarray, n: int,

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .blocks import BLOCK
+from .ref import round_to_codes
 
 ROWS_PER_TILE = 64  # (64, 256) f32 tile = 64 KiB in VMEM
 
@@ -24,7 +25,7 @@ def _quantize_kernel(x_ref, q_ref, s_ref):
     x = x_ref[...].astype(jnp.float32)
     absmax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
     scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
-    q_ref[...] = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
+    q_ref[...] = round_to_codes(x, scale)
     s_ref[...] = scale
 
 
@@ -32,7 +33,7 @@ def _quantize_delta_kernel(x_ref, prev_ref, d_ref, s_ref, q_ref):
     x = x_ref[...].astype(jnp.float32)
     absmax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
     scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
-    q = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
+    q = round_to_codes(x, scale)
     q_ref[...] = q
     d_ref[...] = jnp.bitwise_xor(q, prev_ref[...])
     s_ref[...] = scale

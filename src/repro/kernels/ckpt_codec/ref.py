@@ -13,12 +13,26 @@ host-side wire codec in ``repro.core.tiers`` so the two cannot drift).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
-from .blocks import BLOCK
+from .blocks import BLOCK, SCALE_HEAD_MASK
 
 __all__ = ["BLOCK", "quantize_ref", "dequantize_ref", "xor_delta_ref",
-           "quantize_delta_ref"]
+           "quantize_delta_ref", "round_to_codes"]
+
+
+def round_to_codes(x, scale):
+    """int8 codes nearest ``x / scale``: the jnp twin of
+    :func:`.blocks.round_to_codes_np`, used by the Pallas kernel too."""
+    q = jnp.round(x / scale)
+    head = jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(scale, jnp.int32) & SCALE_HEAD_MASK,
+        jnp.float32)
+    r = (x - q * head) - q * (scale - head)
+    half = 0.5 * scale
+    q = q + jnp.where(r > half, 1.0, 0.0) - jnp.where(r < -half, 1.0, 0.0)
+    return jnp.clip(q, -127, 127).astype(jnp.int8)
 
 
 def quantize_ref(x):
@@ -26,8 +40,7 @@ def quantize_ref(x):
     x = x.astype(jnp.float32)
     absmax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
     scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
-    q = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
-    return q, scale
+    return round_to_codes(x, scale), scale
 
 
 def dequantize_ref(q, scale, dtype=jnp.float32):

@@ -12,32 +12,20 @@ Every kernel package exposes three execution paths:
                     ``cost_analysis()`` reflects the flash-style memory
                     behaviour rather than a naive T x T buffer.
 
-``resolve_impl`` picks a path: explicit argument > REPRO_KERNEL_IMPL env var >
-backend autodetection (TPU -> pallas, otherwise xla).
+``resolve_impl`` picks a path: the explicit argument, else the backend
+(TPU -> pallas, otherwise xla).  On a TPU nothing moves a kernel off Pallas:
+a kernel that does not compile there fails the program.
 """
 from __future__ import annotations
-
-import os
-from functools import lru_cache
 
 VALID_IMPLS = ("pallas", "interpret", "xla", "ref")
 
 
-@lru_cache(maxsize=1)
-def _default_backend() -> str:
-    import jax
-
-    try:
-        return jax.default_backend()
-    except Exception:  # pragma: no cover
-        return "cpu"
-
-
 def resolve_impl(impl: str | None = None) -> str:
-    if impl is None:
-        impl = os.environ.get("REPRO_KERNEL_IMPL") or "auto"
-    if impl == "auto":
-        impl = "pallas" if _default_backend() == "tpu" else "xla"
+    if impl is None or impl == "auto":
+        import jax
+
+        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl not in VALID_IMPLS:
         raise ValueError(f"impl must be one of {VALID_IMPLS} or 'auto', got {impl!r}")
     return impl

@@ -89,7 +89,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         l = l_ref[:, :1]
         l = jnp.where(l == 0, 1.0, l)    # fully-masked rows -> zeros
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[:, :1] + jnp.log(l))[:, 0]
+        # lse rides a full lane axis: a (bq,) block would put a unit dim
+        # second-minor, which Mosaic refuses; the wrapper keeps lane 0
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
@@ -127,11 +129,11 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h, i, j: (b_, h, i)),
+            pl.BlockSpec((1, 1, bq, LANES), lambda b_, h, i, j: (b_, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, tp, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, tp), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, tp, LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
@@ -140,7 +142,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
         ],
         interpret=interpret,
     )(qp, kp, vp)
-    out, lse = out[0][:, :, :t, :], out[1][:, :, :t]
+    out, lse = out[0][:, :, :t, :], out[1][:, :, :t, 0]
     if return_lse:
         return out, lse
     return out

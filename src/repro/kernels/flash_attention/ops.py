@@ -150,7 +150,6 @@ def _xla_flash_bwd(q, k, v, o, lse, do, *, causal, window, scale,
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-# nondiff_argnums (not *_argnames): works on every jax we support
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _attention_core(q, k, v, causal, window, scale, impl, block_q, block_k):
     if impl == "ref":

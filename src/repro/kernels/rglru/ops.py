@@ -40,7 +40,6 @@ def _dispatch(log_a, g, h0, chunk, impl):
                         interpret=(impl == "interpret"))
 
 
-# nondiff_argnums (not *_argnames): works on every jax we support
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _rglru_core(log_a, g, h0, chunk, impl):
     return _dispatch(log_a, g, h0, chunk, impl)

@@ -76,7 +76,6 @@ def _dispatch(r, k, v, log_w, u, s0, chunk, impl):
                         interpret=(impl == "interpret"))
 
 
-# nondiff_argnums (not *_argnames): works on every jax we support
 @partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def _rwkv6_core(r, k, v, log_w, u, s0, chunk, impl):
     return _dispatch(r, k, v, log_w, u, s0, chunk, impl)

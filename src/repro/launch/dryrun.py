@@ -75,8 +75,6 @@ def lower_cell(cfg, shape, mesh, *, rules=None, opt_cfg=None,
 
     mem = compiled.memory_analysis()
     xla_cost = compiled.cost_analysis()
-    if isinstance(xla_cost, (list, tuple)):   # older jax: one dict per device
-        xla_cost = xla_cost[0] if xla_cost else {}
     hlo_text = compiled.as_text()
     analysis = analyze(hlo_text)
     if os.environ.get("REPRO_DRYRUN_TOPS"):

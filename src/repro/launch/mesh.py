@@ -14,7 +14,11 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: ``constrain`` places activations with
+    # with_sharding_constraint, which refuses the Explicit axes that
+    # jax.make_mesh gives by default
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 # TPU v5e-like hardware model used by the roofline analysis (EXPERIMENTS.md)

@@ -33,6 +33,10 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     import jax
 
     from repro.configs import get_config

@@ -14,8 +14,10 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import attention as flash_attention
+from repro.kernels.common import resolve_impl
+from repro.kernels.flash_attention import attention as flash_attention
 from repro.sharding import constrain
+from repro.sharding.rules import active_rules, spec
 
 from .layers import _dense_init, apply_rope
 
@@ -102,6 +104,33 @@ def _project_qkv(params, x, xkv, num_heads, num_kv_heads, head_dim):
     return q, k, v
 
 
+def sharded_flash_attention(q, k, v, *, causal: bool = True,
+                            window: Optional[int] = None,
+                            impl: Optional[str] = None):
+    """``flash_attention`` under the active mesh.
+
+    XLA does not partition a Mosaic kernel, so with Pallas on a mesh of
+    several devices the kernel runs per shard in ``shard_map``, split over
+    the batch axes the rules give (heads whole: a GQA group must not be cut
+    apart from its kv head).  Without a mesh, or on the XLA path, it is the
+    plain call.
+    """
+    active = active_rules()
+    mesh = active[0] if active else None
+    if mesh is None or mesh.size == 1 \
+            or resolve_impl(impl) not in ("pallas", "interpret"):
+        return flash_attention(q, k, v, causal=causal, window=window,
+                               impl=impl)
+    batch = spec(("batch",), active[1], mesh, q.shape[:1])
+    part = jax.sharding.PartitionSpec(*batch, None, None, None)
+    return jax.shard_map(
+        lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                        window=window, impl=impl),
+        mesh=mesh, in_specs=(part, part, part), out_specs=part,
+        # pallas_call's out_shape states no varying mesh axes to check
+        check_vma=False)(q, k, v)
+
+
 def attn_apply(params, x, *, num_heads, num_kv_heads, head_dim,
                positions=None, causal: bool = True,
                window: Optional[int] = None, rope_theta: float = 10000.0,
@@ -124,8 +153,8 @@ def attn_apply(params, x, *, num_heads, num_kv_heads, head_dim,
     q = constrain(q, "batch", "act_heads", "seq", None)
     k = constrain(k, "batch", "act_kv_heads", "kv_seq", None)
     v = constrain(v, "batch", "act_kv_heads", "kv_seq", None)
-    o = flash_attention(q, k, v, causal=causal and self_attn, window=window,
-                        impl=impl)
+    o = sharded_flash_attention(q, k, v, causal=causal and self_attn,
+                                window=window, impl=impl)
     o = o.transpose(0, 2, 1, 3).reshape(b, t, num_heads * head_dim)
     out = o @ params["wo"].astype(x.dtype)
     out = constrain(out, "batch", "seq", "act_embed")

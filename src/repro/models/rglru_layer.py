@@ -11,7 +11,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import rglru as rglru_core
+from repro.kernels.rglru import rglru as rglru_core
 from repro.sharding import constrain
 
 from .layers import _dense_init

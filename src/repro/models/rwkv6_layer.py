@@ -12,7 +12,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import rwkv6 as rwkv6_core
+from repro.kernels.rwkv6 import rwkv6 as rwkv6_core
 from repro.sharding import constrain
 
 from .layers import _dense_init, groupnorm_heads

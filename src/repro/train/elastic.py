@@ -47,9 +47,14 @@ DATA_REGION = "data_state"
 
 
 def default_make_mesh(ranks: int) -> Mesh:
-    devs = jax.devices()[:ranks]
-    if len(devs) < ranks:                    # 1-device CPU: logical ranks
-        devs = jax.devices()
+    devs = jax.devices()
+    if len(devs) >= ranks:
+        devs = devs[:ranks]
+    elif devs[0].platform != "cpu":
+        # an accelerator job is never silently run on fewer chips than ranks
+        raise ValueError(f"{ranks} ranks need {ranks} devices, "
+                         f"{devs[0].platform} has {len(devs)}")
+    # else: CPU test runs keep logical ranks over the host's devices
     return Mesh(np.asarray(devs).reshape(len(devs)), ("data",))
 
 
@@ -63,8 +68,11 @@ class ElasticTrainer:
                  make_mesh: Callable[[int], Mesh] = default_make_mesh,
                  codec: str = "raw", replication: int = 1,
                  total_steps: int = 1000, adaptive_interval: bool = False,
-                 step_sim_s: float = 0.0, overlap_resize: bool = False):
+                 step_sim_s: float = 0.0, overlap_resize: bool = False,
+                 impl: Optional[str] = None):
         self.cfg = cfg
+        # kernel path of the step and the snapshot encode (None: by backend)
+        self.impl = impl
         self.shape = shape
         self.app = MalleableApp(app_id, cluster.rm, ranks)
         self.proc_type = self.app.init_adapt()
@@ -157,7 +165,8 @@ class ElasticTrainer:
                                   self.state)
 
     def _jit_step(self):
-        step_fn = make_train_step(self.cfg, self.opt_cfg, self.schedule)
+        step_fn = make_train_step(self.cfg, self.opt_cfg, self.schedule,
+                                  impl=self.impl)
 
         def run(state, batch):
             with use_rules(self.mesh, self.rules):
@@ -183,7 +192,8 @@ class ElasticTrainer:
         if self.client.codec in ("q8", "q8-delta"):
             snap = snapshot_pytree(self.state, step=step,
                                    codec=self.client.codec,
-                                   chain_lookup=self.client.delta_chain_lookup)
+                                   chain_lookup=self.client.delta_chain_lookup,
+                                   impl=self.impl)
             h = self.client.commit_snapshot(snap, extra_parts=data_parts,
                                             blocking=blocking)
         else:
@@ -350,8 +360,10 @@ class ElasticTrainer:
                 h.cancel()
             self._adapt_handles = None
             self._adapt_ctx = None
-        for h in self._pending_commits:
-            if not h.done():
+        try:
+            # every handle, done or not: a save that already failed raises
+            for h in self._pending_commits:
                 h.wait(timeout=60)
-        self.client.finalize()
-        self._unsubscribe()
+        finally:
+            self.client.finalize()
+            self._unsubscribe()

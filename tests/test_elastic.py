@@ -76,3 +76,26 @@ def test_malleable_state_machine():
     assert app.ranks == 8
     assert app.adaptations == 1
     assert app.probe_adapt() is None
+
+
+def test_finalize_raises_a_failed_async_commit(monkeypatch):
+    """An async save that failed, and is long done, still fails finalize."""
+    from concurrent.futures import Future
+
+    from repro.core import Agent, ICheckError
+
+    def refused_put(self, key, payload, crc=None, *, epoch=None):
+        fut = Future()
+        fut.set_exception(ICheckError(f"store refused {key}"))
+        return fut
+
+    with ICheckCluster(n_icheck_nodes=2) as cluster:
+        t = ElasticTrainer(CFG, SHAPE, cluster, app_id="app", seed=0,
+                           opt_cfg=OPT, commit_every=0, probe_every=0)
+        t.run(1)
+        monkeypatch.setattr(Agent, "put", refused_put)
+        h = t.commit()
+        with pytest.raises(ICheckError, match="store refused"):
+            h.wait(timeout=60)
+        with pytest.raises(ICheckError, match="store refused"):
+            t.finalize()

@@ -5,8 +5,11 @@ needed slices (plan.mesh_moves).  Runs in a subprocess with 8 fake CPU
 devices so the in-process test suite keeps seeing 1 device."""
 import subprocess
 import sys
+from pathlib import Path
 
 import pytest
+
+ROOT = Path(__file__).resolve().parents[1]
 
 SCRIPT = r"""
 import os
@@ -63,6 +66,6 @@ print("ELASTIC_MESH_OK")
 @pytest.mark.dryrun
 def test_mesh_redistribution_across_device_counts():
     out = subprocess.run([sys.executable, "-c", SCRIPT],
-                         capture_output=True, text=True, cwd="/root/repo",
+                         capture_output=True, text=True, cwd=ROOT,
                          timeout=300)
     assert "ELASTIC_MESH_OK" in out.stdout, out.stdout + out.stderr

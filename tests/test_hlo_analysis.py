@@ -2,6 +2,7 @@
 parsing -- validated against modules with known costs."""
 import subprocess
 import sys
+from pathlib import Path
 
 import pytest
 
@@ -9,6 +10,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.launch.hlo import analyze, parse_hlo, top_instructions
+
+ROOT = Path(__file__).resolve().parents[1]
 
 
 def _compile_text(fn, *args):
@@ -78,11 +81,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-try:    # axis_types / AxisType only exist on newer jax
-    mesh = jax.make_mesh((8,), ("model",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
-except (AttributeError, TypeError):
-    mesh = jax.make_mesh((8,), ("model",))
+mesh = jax.make_mesh((8,), ("model",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 sh = NamedSharding(mesh, P(None, "model"))
 f = jax.jit(lambda a, b: (a @ b).sum(), in_shardings=(None, sh))
 a = jax.ShapeDtypeStruct((256, 256), jnp.float32)
@@ -98,5 +98,5 @@ print("COLLECTIVES_OK", res["collectives"]["counts"])
 @pytest.mark.dryrun
 def test_collectives_detected_in_sharded_module():
     out = subprocess.run([sys.executable, "-c", SUB], capture_output=True,
-                         text=True, cwd="/root/repo", timeout=180)
+                         text=True, cwd=ROOT, timeout=180)
     assert "COLLECTIVES_OK" in out.stdout, out.stdout + out.stderr

@@ -6,6 +6,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels.ckpt_codec import (BLOCK, dequantize, quantize,
                                       quantize_delta, undelta_dequantize)
+from repro.kernels.ckpt_codec.blocks import quantize_np
 from repro.kernels.ckpt_codec.ops import _to_blocks
 from repro.kernels.ckpt_codec.ref import quantize_ref
 
@@ -36,6 +37,39 @@ def test_roundtrip_error_bound(shape):
         # per-block error bounded by scale/2 = absmax/254
         err = np.abs(np.asarray(xr) - x)
         assert err.max() <= np.abs(x).max() / 127 * 0.51 + 1e-7
+
+
+def _midpoint_block():
+    """A block whose value x lies just past the midpoint (k + 1/2) * scale
+    of two codes, while the f32 quotient x / scale rounds to k + 1/2
+    exactly: rounding that quotient (half to even, k even) picks k, more
+    than half a scale away from x."""
+    rng = np.random.default_rng(1)
+    while True:
+        absmax = np.float32(rng.uniform(0.5, 2.0))
+        scale = absmax / np.float32(127.0)
+        k = 2 * int(rng.integers(1, 63))
+        mid = (k + 0.5) * np.float64(scale)        # exact in float64
+        x = np.float32(mid)
+        if x <= mid:
+            x = np.nextafter(x, np.float32(np.inf))
+        if x / scale == np.float32(k + 0.5):
+            block = np.zeros((1, BLOCK), np.float32)
+            block[0, 0], block[0, 1] = absmax, x
+            return block, k, scale
+
+
+@pytest.mark.parametrize("impl", ["numpy", "xla", "interpret"])
+def test_code_nearest_at_rounding_midpoint(impl):
+    block, k, scale = _midpoint_block()
+    if impl == "numpy":
+        q, s = quantize_np(block)
+    else:
+        q, s = quantize(block, impl=impl)
+    assert np.asarray(s)[0, 0] == scale
+    assert int(np.asarray(q)[0, 1]) == k + 1
+    assert abs(np.float64(block[0, 1]) - (k + 1) * np.float64(scale)) \
+        <= scale / 2
 
 
 def test_delta_identical_is_zero():
