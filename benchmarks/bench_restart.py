@@ -169,8 +169,9 @@ TRACE_OVERHEAD_TOL = 0.03
 
 def _trace_leg(data, trace: bool, trace_path=None) -> dict:
     """One tracing leg: identical cluster + checkpoint workload, only the
-    tracer differs.  Spans read the sim clock but never advance it, so the
-    traced leg's sim-time throughput must match the untraced one."""
+    tracer differs.  Spans read the clocks but never advance the sim
+    clock, so the traced leg's sim-time throughput must match the untraced
+    one."""
     with ICheckCluster(n_icheck_nodes=2, n_spare_nodes=0,
                        node_memory=2 << 30, nic_bandwidth=1e9,
                        trace=trace, trace_path=trace_path) as c:

@@ -531,9 +531,10 @@ def run_campaign(seed: int, schedule: Optional[ChaosSchedule] = None,
                                          controller_crash=controller_crash)
     horizon = schedule.horizon_s
     apps = ("alpha", "beta")
-    # trace=True: spans only read the sim clock, so tracing is free of
-    # side effects on determinism — and a red seed's flight dump then
-    # carries the span tree of the failure window, not just raw events
+    # trace=True: spans read the clocks but never advance the sim clock or
+    # steer control flow, so tracing leaves the schedule deterministic —
+    # and a red seed's flight dump then carries the span tree of the
+    # failure window (sim_t beside each wall time), not just raw events
     cluster = ICheckCluster(n_icheck_nodes=3, n_spare_nodes=2,
                             adaptive_interval=False, l3=True,
                             keep_l1=3, keep_l2=2, keep_l3=4,

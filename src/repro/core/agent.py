@@ -439,6 +439,8 @@ class Agent:
             sim += extra
         self._check_alive()  # may have died mid-transfer
         self.store.put(op.key, payload, crc=op.crc)
+        if self.tracer is not None:
+            self.tracer.note(bytes=len(payload))
         rec = TransferRecord(key=op.key, nbytes=len(payload),
                              agent_id=self.agent_id, sim_seconds=sim)
         with self._lock:

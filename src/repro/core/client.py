@@ -22,7 +22,6 @@ from __future__ import annotations
 import dataclasses
 import queue
 import threading
-import time
 from concurrent.futures import Future
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -83,11 +82,13 @@ class CommitHandle:
     # -- executed on the client's completer thread --------------------------
     def _complete(self) -> None:
         ctl = self.client.controller
-        with ctl.tracer.use(self.trace):
+        with ctl.tracer.use(self.trace), ctl.tracer.span(
+                "l1_commit", trace_id_for(self.meta.app_id,
+                                          self.meta.ckpt_id),
+                f"client/{self.meta.app_id}"):
             self._complete_traced(ctl)
 
     def _complete_traced(self, ctl) -> None:
-        t0 = ctl.clock.now()
         per_node_sim: Dict[str, float] = {}
         try:
             frag_agent: Dict[ShardKey, str] = {}
@@ -116,13 +117,14 @@ class CommitHandle:
             self.sim_duration = max(per_node_sim.values(), default=0.0)
             ctl.tracer.record(
                 "l1_store", trace_id_for(self.meta.app_id, self.meta.ckpt_id),
-                f"client/{self.meta.app_id}", t0=t0,
-                dur_s=self.sim_duration, retries=self.retries)
+                f"client/{self.meta.app_id}", sim_s=self.sim_duration,
+                retries=self.retries)
             ctl.finalize_checkpoint(self.meta, drain=self._drain)
             self.client._last_commit_sim_s = self.sim_duration
             logical_bytes = (
                 sum(n for n, _ in self._logical.values())
                 + sum(len(p) for k, p, _ in self._puts if k.replica == 0))
+            ctl.tracer.note(bytes=logical_bytes)
             ctl.bus.publish(E.COMMIT_DONE, app=self.meta.app_id,
                             ckpt=self.meta.ckpt_id, step=self.meta.step,
                             bytes=logical_bytes,
@@ -292,7 +294,7 @@ class ResizeCutoverHandle:
         stall_s = stats["stall_sim_s"] + stall_fetch_s
         if self.trace_id is not None:
             ctl.tracer.record("cutover", self.trace_id,
-                              f"client/{client.app_id}", dur_s=stall_s,
+                              f"client/{client.app_id}", sim_s=stall_s,
                               region=self.name, overlap_s=overlap_s,
                               tail_frames=stats["tail_frames"],
                               rehydrated=stats["rehydrated"])
@@ -458,8 +460,8 @@ class ICheckClient:
                parts_by_region: Dict[str, Dict[int, np.ndarray]],
                userdata: bytes = b"", blocking: bool = False,
                drain: bool = True,
-               encoded: Optional[Dict[str, EncodedRegion]] = None
-               ) -> CommitHandle:
+               encoded: Optional[Dict[str, EncodedRegion]] = None,
+               snapshot_trace=None) -> CommitHandle:
         """icheck_commit(): notify agents, return immediately.
 
         ``parts_by_region[name][part]`` is the local array of that part
@@ -472,9 +474,26 @@ class ICheckClient:
         XOR-delta frame against the catalog's previous-codes state (full
         keyframe every K commits, after a chain reset, or when churn makes
         the delta no smaller than a keyframe).
+
+        ``snapshot_trace`` (``HostSnapshot.trace``) is the traced snapshot
+        the parts came from: it joins this checkpoint's trace tree.
         """
         if not self._initialized:
             raise ICheckError("call init() first")
+        tracer = self.controller.tracer
+        prov = tracer.provisional(self.app_id)
+        with tracer.span("commit", prov, f"client/{self.app_id}", root=True,
+                         step=step, drain=drain):
+            handle = self._commit(prov, step, parts_by_region, userdata,
+                                  drain, encoded, snapshot_trace)
+            if blocking:
+                handle.wait(timeout=120)
+        return handle
+
+    def _commit(self, prov, step, parts_by_region, userdata, drain, encoded,
+                snapshot_trace) -> CommitHandle:
+        """The body of :meth:`commit`, inside its root span (``prov``: the
+        span's provisional trace until the catalog names the checkpoint)."""
         encoded = dict(encoded or {})
         overlap = set(encoded) & set(parts_by_region)
         if overlap:
@@ -506,54 +525,64 @@ class ICheckClient:
                                                   chain=None)
             else:
                 metas[name] = meta
-        ckpt = ctl.new_checkpoint(self.app_id, step, metas, userdata=userdata)
+        tracer = ctl.tracer
+        track = f"client/{self.app_id}"
+        with tracer.span("commit/catalog", prov, track):
+            ckpt = ctl.new_checkpoint(self.app_id, step, metas,
+                                      userdata=userdata)
         agents = ctl.agents_for(self.app_id)
         if not agents:
             raise ICheckError("no agents assigned")
 
         # root of this checkpoint's trace tree: every later phase (agent
-        # puts, L2 drain, L3 trickle, a restore hours later) attaches here
+        # puts, L2 drain, L3 trickle, a restore hours later) attaches here,
+        # and the snapshot the parts came from hangs under it
         trace_id = trace_id_for(self.app_id, ckpt.ckpt_id)
-        root_ctx = ctl.tracer.record("commit", trace_id,
-                                     f"client/{self.app_id}", root=True,
-                                     step=step, drain=drain)
+        root_ctx = tracer.adopt(prov, trace_id)
+        if snapshot_trace is not None:
+            tracer.adopt(snapshot_trace.trace_id, trace_id, parent=root_ctx)
+        tracer.note(ckpt=ckpt.ckpt_id)
 
-        t_enc = time.monotonic()
         stats = {"raw": 0, "enc": 0, "key": 0, "delta": 0,
                  "encode_s": 0.0, "publish": False}
         payloads: Dict[str, Dict[int, bytes]] = {}
-        try:
-            for name, parts in parts_by_region.items():
-                meta = metas[name]
-                raw = {part: np.ascontiguousarray(arr).tobytes()
-                       for part, arr in parts.items()}
-                if meta.codec == "q8-delta":
-                    payloads[name] = self._encode_delta_host(
-                        ckpt.ckpt_id, meta, raw, stats)
-                else:
-                    blobs = {
-                        part: encode_payload(data, meta.codec, meta.dtype)
-                        for part, data in raw.items()}
-                    if meta.codec == "q8":
-                        # plain q8 feeds the same codec gauges (its ~4x
-                        # ratio must not read as "codec did nothing")
-                        stats["raw"] += sum(len(b) for b in raw.values())
-                        stats["enc"] += sum(len(b) for b in blobs.values())
-                        stats["publish"] = True
-                    payloads[name] = blobs
-            for name, enc in encoded.items():
-                payloads[name] = self._adopt_encoded(ckpt.ckpt_id,
-                                                     metas[name], enc, stats)
-        except BaseException:
-            # some chains may already reference this checkpoint's frames,
-            # which will never be stored — reset so the next commit keyframes
-            ctl.reset_delta_chains(self.app_id, reason="commit_encode_failed")
-            raise
-        stats["encode_s"] += time.monotonic() - t_enc
-        ctl.tracer.record("encode", trace_id, f"client/{self.app_id}",
-                          dur_s=stats["encode_s"], parent=root_ctx,
-                          raw_bytes=stats["raw"],
-                          encoded_bytes=stats["enc"])
+        with tracer.timed("encode", trace_id, track,
+                          parent=root_ctx) as timing:
+            try:
+                for name, parts in parts_by_region.items():
+                    meta = metas[name]
+                    raw = {part: np.ascontiguousarray(arr).tobytes()
+                           for part, arr in parts.items()}
+                    if meta.codec == "q8-delta":
+                        payloads[name] = self._encode_delta_host(
+                            ckpt.ckpt_id, meta, raw, stats)
+                    else:
+                        blobs = {
+                            part: encode_payload(data, meta.codec,
+                                                 meta.dtype)
+                            for part, data in raw.items()}
+                        if meta.codec == "q8":
+                            # plain q8 feeds the same codec gauges (its ~4x
+                            # ratio must not read as "codec did nothing")
+                            stats["raw"] += sum(len(b) for b in raw.values())
+                            stats["enc"] += sum(len(b)
+                                                for b in blobs.values())
+                            stats["publish"] = True
+                        payloads[name] = blobs
+                for name, enc in encoded.items():
+                    payloads[name] = self._adopt_encoded(
+                        ckpt.ckpt_id, metas[name], enc, stats)
+            except BaseException:
+                # some chains may already reference this checkpoint's
+                # frames, which will never be stored — reset so the next
+                # commit keyframes
+                ctl.reset_delta_chains(self.app_id,
+                                       reason="commit_encode_failed")
+                raise
+            tracer.note(raw_bytes=stats["raw"], encoded_bytes=stats["enc"],
+                        key_frames=stats["key"],
+                        delta_frames=stats["delta"])
+        stats["encode_s"] += timing.seconds
 
         puts: List[Tuple[ShardKey, bytes, Agent]] = []
         logical: Dict[ShardKey, Tuple[int, int]] = {}
@@ -600,8 +629,6 @@ class ICheckClient:
         handle = CommitHandle(self, ckpt, puts, drain=drain, trace=root_ctx,
                               logical=logical)
         self._commit_q.put(handle)
-        if blocking:
-            handle.wait(timeout=120)
         return handle
 
     def _encode_delta_host(self, ckpt_id: int, meta: RegionMeta,
@@ -686,7 +713,8 @@ class ICheckClient:
                  if sr.encoded is None}
         parts.update(extra_parts or {})
         return self.commit(snap.step, parts, userdata=userdata,
-                           blocking=blocking, drain=drain, encoded=encoded)
+                           blocking=blocking, drain=drain, encoded=encoded,
+                           snapshot_trace=snap.trace)
 
     def delta_chain_lookup(self, name: str, num_parts: int):
         """Previous-codes state for a device-side delta encode (or None when
@@ -701,18 +729,13 @@ class ICheckClient:
             handle._complete()
 
     # --------------------------------------------------------------- restart
-    def _fetch_decoded(self, region: RegionMeta, ckpt_id: int, part: int,
-                       stats: Optional[dict] = None) -> bytes:
-        """Fetch + decode one region part, replaying the delta chain
-        (keyframe → deltas) for ``q8-delta`` regions.  ``stats`` (when
-        given) accumulates the wire bytes that flowed through this client —
-        the redistribution funnel's bytes-through-client accounting."""
+    def _fetch_frames(self, region: RegionMeta, ckpt_id: int,
+                      part: int) -> List[bytes]:
+        """The stored frames of one region part: the keyframe → deltas
+        chain for ``q8-delta`` regions, else the one shard."""
         if region.codec != "q8-delta":
-            blob = self.controller.fetch_shard(self.app_id, ckpt_id,
-                                               region.name, part)
-            if stats is not None:
-                stats["wire_bytes"] += len(blob)
-            return decode_payload(blob, region.codec, region.dtype)
+            return [self.controller.fetch_shard(self.app_id, ckpt_id,
+                                                region.name, part)]
         chain = region.chain or (ckpt_id,)
         blobs = []
         for cid in chain:
@@ -723,9 +746,24 @@ class ICheckClient:
                 raise RestoreError(
                     f"delta chain of {region.name!r} part {part} is broken: "
                     f"frame ckpt={cid} is gone (chain {chain})") from e
-            if stats is not None:
-                stats["wire_bytes"] += len(blobs[-1])
+        return blobs
+
+    @staticmethod
+    def _decode_frames(region: RegionMeta, blobs: List[bytes]) -> bytes:
+        if region.codec != "q8-delta":
+            return decode_payload(blobs[0], region.codec, region.dtype)
         return q8_chain_decode(blobs, region.dtype)
+
+    def _fetch_decoded(self, region: RegionMeta, ckpt_id: int, part: int,
+                       stats: Optional[dict] = None) -> bytes:
+        """Fetch + decode one region part, replaying the delta chain
+        (keyframe → deltas) for ``q8-delta`` regions.  ``stats`` (when
+        given) accumulates the wire bytes that flowed through this client —
+        the redistribution funnel's bytes-through-client accounting."""
+        blobs = self._fetch_frames(region, ckpt_id, part)
+        if stats is not None:
+            stats["wire_bytes"] += sum(len(b) for b in blobs)
+        return self._decode_frames(region, blobs)
 
     def _ckpt_region(self, ckpt_id: int, name: str) -> RegionMeta:
         """The per-checkpoint RegionMeta (carries frame/chain) when known;
@@ -756,15 +794,24 @@ class ICheckClient:
         out: Dict[str, Dict[int, np.ndarray]] = {}
         # the restore span re-joins the checkpoint's trace tree by id alone
         # (the commit may be hours old; no context survived to here)
-        with ctl.tracer.span("restore",
-                             trace_id_for(self.app_id, meta.ckpt_id),
-                             f"client/{self.app_id}", tier=level):
+        tracer = ctl.tracer
+        trace_id = trace_id_for(self.app_id, meta.ckpt_id)
+        track = f"client/{self.app_id}"
+        with tracer.span("restore", trace_id, track, tier=level):
             for name, region in meta.regions.items():
                 parts: Dict[int, np.ndarray] = {}
                 for part in range(region.partition.num_parts):
-                    payload = self._fetch_decoded(region, meta.ckpt_id, part)
-                    arr = np.frombuffer(bytearray(payload),
-                                        dtype=np.dtype(region.dtype))
+                    with tracer.span("restore/fetch", trace_id, track,
+                                     region=name):
+                        blobs = self._fetch_frames(region, meta.ckpt_id,
+                                                   part)
+                        tracer.note(bytes=sum(len(b) for b in blobs))
+                    with tracer.span("restore/decode", trace_id, track,
+                                     region=name):
+                        arr = np.frombuffer(
+                            bytearray(self._decode_frames(region, blobs)),
+                            dtype=np.dtype(region.dtype))
+                        tracer.note(bytes=arr.nbytes)
                     parts[part] = arr.reshape(self._part_shape(region, part))
                 out[name] = parts
                 # refresh the client-side region registry from the manifest
@@ -866,7 +913,6 @@ class ICheckClient:
         fetch of the wanted parts."""
         ctl = self.controller
         region = self._ckpt_region(ckpt_id, name)
-        t0 = ctl.clock.now()
         results, stats = ctl.execute_redistribution(self.app_id, region,
                                                     ckpt_id, programs)
         try:
@@ -887,7 +933,7 @@ class ICheckClient:
         sim_s = stats["sim_s"] + max(fetch_lane.values(), default=0.0)
         ctl.tracer.record("redistribute_peer",
                           trace_id_for(self.app_id, ckpt_id),
-                          f"client/{self.app_id}", t0=t0, dur_s=sim_s,
+                          f"client/{self.app_id}", sim_s=sim_s,
                           region=name, new_parts=new_parts)
         self._publish_redistribution_done(
             name, new_parts, "peer", sim_s, bytes_client, stats,
@@ -918,8 +964,8 @@ class ICheckClient:
         result = {p: dst[p] for p in wanted}
         ctl.tracer.record("redistribute_funnel",
                           trace_id_for(self.app_id, ckpt_id),
-                          f"client/{self.app_id}", t0=t0,
-                          dur_s=ctl.clock.now() - t0, region=name,
+                          f"client/{self.app_id}",
+                          sim_s=ctl.clock.now() - t0, region=name,
                           new_parts=new_num_parts)
         self._publish_redistribution_done(name, new_num_parts, "client",
                                           ctl.clock.now() - t0,
@@ -1041,8 +1087,8 @@ class ICheckClient:
         result = {p: dst[p] for p in wanted}
         ctl.tracer.record("redistribute_funnel",
                           trace_id_for(self.app_id, ckpt_id),
-                          f"client/{self.app_id}", t0=t0,
-                          dur_s=ctl.clock.now() - t0, region=name,
+                          f"client/{self.app_id}",
+                          sim_s=ctl.clock.now() - t0, region=name,
                           new_parts=len(new_boxes))
         self._publish_redistribution_done(name, len(new_boxes), "client",
                                           ctl.clock.now() - t0,

@@ -16,9 +16,10 @@ cold restarts fall back to the object store when L1 and L2 are gone.
 ``watermark_high``/``watermark_low`` drive the proactive L1 demotion policy
 on nodes that have a spill tier (``spill_bytes > 0``).
 
-``trace=True`` (or any ``trace_path=``) turns on sim-time checkpoint
+``trace=True`` (or any ``trace_path=``) turns on wall-clock checkpoint
 tracing: one causal span tree per checkpoint, exported as Chrome/Perfetto
-``trace_event`` JSON to ``trace_path`` when the cluster closes.  ``obs_dir``
+``trace_event`` JSON to ``trace_path`` when the cluster closes (each span
+also carries the sim time it started at).  ``obs_dir``
 overrides where flight-recorder crash dumps land (default
 ``artifacts/obs/``)."""
 from __future__ import annotations
@@ -100,7 +101,7 @@ class ICheckCluster:
 
     @property
     def tracer(self):
-        """The controller's TraceCollector (sim-time checkpoint tracing)."""
+        """The controller's TraceCollector (wall-clock checkpoint tracing)."""
         return self.controller.tracer
 
     @property

@@ -658,3 +658,4 @@ class Controller:
             self.journal.close()
         for mgr in self.managers():
             mgr.close()
+        self.tracer.disable()

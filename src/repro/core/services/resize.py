@@ -280,7 +280,7 @@ class PeerRedistributionEngine:
         stats["window_skew"] = stats["sim_s"] / wall if wall > 0 else 1.0
         ctl.tracer.record("redistribute_window",
                           trace_id_for(app_id, ckpt_id), "resize/engine",
-                          t0=t0, dur_s=stats["sim_s"], region=region.name,
+                          sim_s=stats["sim_s"], region=region.name,
                           new_parts=len(programs),
                           peer_hops=stats.get("peer_hops", 0))
         return results, stats

@@ -253,7 +253,8 @@ def q8_quantize_part(data: bytes, dtype: str) -> Tuple[int, np.ndarray,
 
 def pack_q8_region(parts: Dict[int, Tuple[int, np.ndarray, np.ndarray]],
                    prev: Optional[Dict[int, DeltaState]],
-                   deltas: Optional[Dict[int, np.ndarray]] = None
+                   deltas: Optional[Dict[int, np.ndarray]] = None,
+                   info: Optional[dict] = None
                    ) -> Tuple[Dict[int, bytes], Dict[int, DeltaState], str]:
     """Frame one region's quantized parts as deltas or keyframes.
 
@@ -264,10 +265,13 @@ def pack_q8_region(parts: Dict[int, Tuple[int, np.ndarray, np.ndarray]],
     matching previous-codes state **and** the delta frames are actually
     smaller than keyframes (high-churn commits fall back to a keyframe, so
     q8-delta never loses to plain q8); returns ``(blobs, new_states,
-    frame)`` with frame ``"key"`` or ``"delta"``.
+    frame)`` with frame ``"key"`` or ``"delta"``.  ``info["delta_built"]``
+    (when given) says whether delta frames were built, kept or not.
     """
     states = {p: DeltaState(n=n, codes=codes, scales=scales)
               for p, (n, codes, scales) in parts.items()}
+    if info is not None:
+        info["delta_built"] = False
     if prev is not None and set(prev) == set(parts):
         delta_blobs: Dict[int, bytes] = {}
         for p, (n, codes, scales) in parts.items():
@@ -277,6 +281,8 @@ def pack_q8_region(parts: Dict[int, Tuple[int, np.ndarray, np.ndarray]],
                 break
             delta_blobs[p] = blob
         if len(delta_blobs) == len(parts):
+            if info is not None:
+                info["delta_built"] = True
             key_total = sum(_q8_full_size(codes.shape[0])
                             for _, codes, _ in parts.values())
             if sum(len(b) for b in delta_blobs.values()) < key_total:

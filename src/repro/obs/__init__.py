@@ -1,4 +1,4 @@
-"""Observability substrate: sim-time tracing, histograms, flight recorder.
+"""Observability substrate: wall-clock tracing, histograms, flight recorder.
 
 See ARCHITECTURE.md "Observability" for the span taxonomy and the
 ``trace_id`` convention.

@@ -37,6 +37,7 @@ from repro.core import events as icheck_events
 from repro.core import plan as planlib
 from repro.core.snapshot import leaf_names, restore_pytree
 from repro.data import SyntheticLMData
+from repro.obs import trace_id_for
 from repro.optim import AdamWConfig, warmup_cosine
 from repro.sharding import get_rules, use_rules
 
@@ -70,74 +71,84 @@ class ElasticTrainer:
                  total_steps: int = 1000, adaptive_interval: bool = False,
                  step_sim_s: float = 0.0, overlap_resize: bool = False,
                  impl: Optional[str] = None):
-        self.cfg = cfg
-        # kernel path of the step and the snapshot encode (None: by backend)
-        self.impl = impl
-        self.shape = shape
-        self.app = MalleableApp(app_id, cluster.rm, ranks)
-        self.proc_type = self.app.init_adapt()
-        self.client = ICheckClient(app_id, cluster.controller, ranks=ranks,
-                                   codec=codec, replication=replication)
-        self.make_mesh = make_mesh
-        self.mesh = make_mesh(ranks)
-        self.rules = get_rules(cfg.rules)
-        self.commit_every = commit_every
-        self.probe_every = probe_every
-        self.global_batch = global_batch or shape.global_batch
-        self.opt_cfg = opt_cfg or AdamWConfig()
-        self.schedule = warmup_cosine(self.opt_cfg.lr, warmup=20,
-                                      total=total_steps)
-        self.data = SyntheticLMData(cfg, shape, seed=seed)
-        self.metrics_log: list = []
-        self.resizes = 0
-        self._pending_commits: list = []
-        # zero-stall resize: on a forewarned/probed resize, open overlap
-        # windows per region and keep training while the base checkpoint
-        # streams; the adapt window proper shrinks to the cutover
-        self.overlap_resize = overlap_resize
-        self._adapt_handles: Optional[Dict[str, object]] = None
-        self._adapt_ctx: Optional[dict] = None
-        self.steps_during_resize = 0
-        # adaptive checkpoint pacing: when enabled, commits follow the
-        # IntervalController's solved cadence (sim-time based, re-announced
-        # via INTERVAL_CHANGED events) instead of the static commit_every
-        # step count; step_sim_s is the simulated compute cost per training
-        # step, which is what advances the cadence clock in tests/benchmarks
-        self.adaptive_interval = adaptive_interval
-        self.step_sim_s = float(step_sim_s)
-        self._clock = cluster.controller.clock
-        if adaptive_interval and self.step_sim_s <= 0 \
-                and self._clock.time_scale == 0:
-            # nothing would ever advance the cadence clock between commits:
-            # the trainer would silently never checkpoint
-            raise ValueError(
-                "adaptive_interval=True needs step_sim_s > 0 (or a cluster "
-                "with time_scale > 0) so sim time advances between steps")
-        self._last_commit_t = self._clock.now()
-        self.interval_changes = 0
-        # checkpoint-service telemetry: observe the controller's event bus
-        # instead of polling its audit list (drain completions, forewarnings,
-        # codec degradations all land here asynchronously)
-        self.ckpt_events: list = []
-        self._unsubscribe = cluster.controller.bus.subscribe(
-            self._on_ckpt_event,
-            events=(icheck_events.CKPT_IN_L1, icheck_events.CKPT_IN_L2,
-                    icheck_events.DRAIN_FAILED, icheck_events.CODEC_DEGRADED,
-                    icheck_events.RESIZE_FOREWARNED,
-                    icheck_events.INTERVAL_CHANGED))
+        # the trainer's own trace: its construction, restart and steps
+        # (each checkpoint's snapshot joins that checkpoint's trace)
+        tracer = self.tracer = cluster.controller.tracer
+        self.trace_id = tracer.unique(f"{app_id}/trainer")
+        self._track = f"trainer/{app_id}"
+        with tracer.span("trainer_init", self.trace_id, self._track,
+                         root=True):
+            self.cfg = cfg
+            # kernel path of the step and the snapshot encode (None: by backend)
+            self.impl = impl
+            self.shape = shape
+            self.app = MalleableApp(app_id, cluster.rm, ranks)
+            self.proc_type = self.app.init_adapt()
+            self.client = ICheckClient(app_id, cluster.controller, ranks=ranks,
+                                       codec=codec, replication=replication)
+            self.make_mesh = make_mesh
+            self.mesh = make_mesh(ranks)
+            self.rules = get_rules(cfg.rules)
+            self.commit_every = commit_every
+            self.probe_every = probe_every
+            self.global_batch = global_batch or shape.global_batch
+            self.opt_cfg = opt_cfg or AdamWConfig()
+            self.schedule = warmup_cosine(self.opt_cfg.lr, warmup=20,
+                                          total=total_steps)
+            self.data = SyntheticLMData(cfg, shape, seed=seed)
+            self.metrics_log: list = []
+            self.resizes = 0
+            self._pending_commits: list = []
+            # zero-stall resize: on a forewarned/probed resize, open overlap
+            # windows per region and keep training while the base checkpoint
+            # streams; the adapt window proper shrinks to the cutover
+            self.overlap_resize = overlap_resize
+            self._adapt_handles: Optional[Dict[str, object]] = None
+            self._adapt_ctx: Optional[dict] = None
+            self.steps_during_resize = 0
+            # adaptive checkpoint pacing: when enabled, commits follow the
+            # IntervalController's solved cadence (sim-time based, re-announced
+            # via INTERVAL_CHANGED events) instead of the static commit_every
+            # step count; step_sim_s is the simulated compute cost per training
+            # step, which is what advances the cadence clock in tests/benchmarks
+            self.adaptive_interval = adaptive_interval
+            self.step_sim_s = float(step_sim_s)
+            self._clock = cluster.controller.clock
+            if adaptive_interval and self.step_sim_s <= 0 \
+                    and self._clock.time_scale == 0:
+                # nothing would ever advance the cadence clock between commits:
+                # the trainer would silently never checkpoint
+                raise ValueError(
+                    "adaptive_interval=True needs step_sim_s > 0 (or a cluster "
+                    "with time_scale > 0) so sim time advances between steps")
+            self._last_commit_t = self._clock.now()
+            self.interval_changes = 0
+            # checkpoint-service telemetry: observe the controller's event bus
+            # instead of polling its audit list (drain completions, forewarnings,
+            # codec degradations all land here asynchronously)
+            self.ckpt_events: list = []
+            self._unsubscribe = cluster.controller.bus.subscribe(
+                self._on_ckpt_event,
+                events=(icheck_events.CKPT_IN_L1, icheck_events.CKPT_IN_L2,
+                        icheck_events.DRAIN_FAILED, icheck_events.CODEC_DEGRADED,
+                        icheck_events.RESIZE_FOREWARNED,
+                        icheck_events.INTERVAL_CHANGED))
 
-        key = jax.random.key(seed)
-        self.state = make_train_state(cfg, key, self.opt_cfg)
-        self._shard_state()
-        self._jit_step()
+            with tracer.span("trainer_init/state", self.trace_id, self._track):
+                key = jax.random.key(seed)
+                self.state = make_train_state(cfg, key, self.opt_cfg)
+                self._shard_state()
+                est = int(sum(np.prod(leaf.shape) * leaf.dtype.itemsize
+                              for leaf in jax.tree.leaves(self.state)))
+                tracer.note(bytes=est)
+            self._jit_step()
 
-        # icheck_init + add_adapt + (maybe) restart -- paper lines 5..9
-        est = sum(np.prod(leaf.shape) * leaf.dtype.itemsize
-                  for leaf in jax.tree.leaves(self.state))
-        self.client.init(ckpt_bytes_estimate=int(est))
-        self._register_regions()
-        restored = self.restart_if_available()
-        self.restarted = restored
+            # icheck_init + add_adapt + (maybe) restart -- paper lines 5..9
+            self.client.init(ckpt_bytes_estimate=est)
+            with tracer.span("trainer_init/register", self.trace_id,
+                             self._track):
+                self._register_regions()
+        self.restarted = self.restart_if_available()
 
     def _on_ckpt_event(self, ev) -> None:
         self.ckpt_events.append(ev.as_record())
@@ -175,7 +186,9 @@ class ElasticTrainer:
         self._step = jax.jit(run, donate_argnums=0)
 
     def _register_regions(self):
-        snap = snapshot_pytree(self.state, step=int(self.state.step))
+        snap = snapshot_pytree(self.state, step=int(self.state.step),
+                               tracer=self.tracer)
+        self.tracer.note(bytes=snap.total_bytes())
         self.client.add_adapt_snapshot(snap)
         self.client.add_adapt(DATA_REGION, (2,), "int64",
                               num_parts=1)
@@ -193,32 +206,39 @@ class ElasticTrainer:
             snap = snapshot_pytree(self.state, step=step,
                                    codec=self.client.codec,
                                    chain_lookup=self.client.delta_chain_lookup,
-                                   impl=self.impl)
+                                   impl=self.impl, tracer=self.tracer)
             h = self.client.commit_snapshot(snap, extra_parts=data_parts,
                                             blocking=blocking)
         else:
-            snap = snapshot_pytree(self.state, step=step)
+            snap = snapshot_pytree(self.state, step=step, tracer=self.tracer)
             self.client.add_adapt_snapshot(snap)   # refresh region boxes
             parts = {name: r.parts for name, r in snap.regions.items()}
             parts.update(data_parts)
-            h = self.client.commit(step, parts, blocking=blocking)
+            h = self.client.commit(step, parts, blocking=blocking,
+                                   snapshot_trace=snap.trace)
         self._pending_commits.append(h)
         self._last_commit_t = self._clock.now()
         return h
 
     def restart_if_available(self) -> bool:
         """icheck_restart: newest complete checkpoint -> TrainState."""
-        found = self.client.restart()
-        if found is None:
-            return False
-        meta, regions, level = found
-        data_parts = regions.pop(DATA_REGION)
-        self.data.restore(data_parts[0])
-        template = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.state)
-        region_meta = {name: meta.regions[name] for name in regions}
-        self.state = restore_pytree(template, regions, region_meta)
-        self._shard_state()
+        with self.tracer.span("restart", self.trace_id, self._track):
+            found = self.client.restart()
+            if found is None:
+                return False
+            meta, regions, level = found
+            with self.tracer.span("restore/place",
+                                  trace_id_for(self.client.app_id,
+                                               meta.ckpt_id), self._track):
+                data_parts = regions.pop(DATA_REGION)
+                self.data.restore(data_parts[0])
+                template = jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                    self.state)
+                region_meta = {name: meta.regions[name] for name in regions}
+                self.state = restore_pytree(template, regions, region_meta,
+                                            tracer=self.tracer)
+                self._shard_state()
         return True
 
     # ---------------------------------------------------------------- resize
@@ -329,17 +349,18 @@ class ElasticTrainer:
         t0 = time.monotonic()
         for _ in range(steps):
             self.maybe_adapt()
-            batch = self.data.next_batch(self.global_batch)
-            batch = {k: jax.device_put(v, self._batch_sharding())
-                     for k, v in batch.items()}
-            self.state, metrics = self._step(self.state, batch)
-            step = int(self.state.step)
+            with self.tracer.span("step", self.trace_id, self._track):
+                batch = self.data.next_batch(self.global_batch)
+                batch = {k: jax.device_put(v, self._batch_sharding())
+                         for k, v in batch.items()}
+                self.state, metrics = self._step(self.state, batch)
+                step = int(self.state.step)
+                self.metrics_log.append(
+                    {"step": step, "loss": float(metrics["loss"])})
             if self._adapt_handles is not None:
                 # work retained inside the adapt window — the whole point of
                 # overlapping: a stop-the-world resize gets zero of these
                 self.steps_during_resize += 1
-            self.metrics_log.append(
-                {"step": step, "loss": float(metrics["loss"])})
             if self.step_sim_s > 0:
                 self._clock.sleep(self.step_sim_s)
             if self._commit_due(step):

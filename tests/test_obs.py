@@ -462,3 +462,291 @@ def test_tracer_bounded_spans():
         t.record(f"s{i}", "t/c0", "trk")
     assert len(t.spans()) == 3 and t.dropped == 2
     assert t.to_chrome_trace()["otherData"]["dropped_spans"] == 2
+
+
+# ------------------------------------------------------ wall-clock spans
+def test_wall_clock_spans_nest_with_positive_durations():
+    import time
+
+    t = TraceCollector(enabled=True)
+    with t.span("outer", "t/c0", "trk", root=True) as outer:
+        time.sleep(0.002)
+        with t.span("inner", "t/c0", "trk") as inner:
+            time.sleep(0.002)
+        t.record("after", "t/c0", "trk", sim_s=4.0)
+    t.disable()
+    by = {s.name: s for s in t.spans()}
+    o, i, a = by["outer"], by["inner"], by["after"]
+    assert i.parent_id == outer.span_id and a.parent_id == outer.span_id
+    assert inner.parent_id == outer.span_id and o.parent_id is None
+    assert o.dur_s >= 0.004 and i.dur_s >= 0.002
+    assert o.t0 < i.t0 and i.t0 + i.dur_s <= o.t0 + o.dur_s
+    assert i.t0 + i.dur_s <= a.t0 <= o.t0 + o.dur_s
+    # a modeled duration is an argument; the record itself is an instant
+    assert a.args["sim_s"] == 4.0 and a.dur_s == 0.0
+    assert t.to_chrome_trace()["otherData"]["clock"] == "wall"
+
+
+def test_disabled_collector_records_nothing_and_imports_no_jax():
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from repro.obs import TraceCollector\n"
+        "t = TraceCollector()\n"
+        "with t.span('a', 't/c0', 'trk') as ctx, "
+        "t.timed('b', 't/c0', 'trk') as tm:\n"
+        "    t.note(bytes=1)\n"
+        "    assert ctx is None and t.record('c', 't/c0', 'trk') is None\n"
+        "assert tm.seconds >= 0 and t.provisional('x') is None\n"
+        "assert t.adopt('x/pending1', 't/c0') is None\n"
+        "assert t.spans() == [] and t.trace_ids() == []\n"
+        "t.enable(); t.disable()\n"
+        "assert 'jax' not in sys.modules, 'jax imported'\n"
+        "print('ok')\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    p = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0 and p.stdout.strip() == "ok", p.stderr
+
+
+def test_forced_gc_is_a_gc_span():
+    import gc
+
+    t = TraceCollector(enabled=True)
+    gc.collect()                      # outside any span: not attributed
+    assert t.spans() == []
+    with t.span("outer", "t/c0", "trk", root=True) as outer:
+        gc.collect()
+    t.disable()
+    gcs = [s for s in t.spans() if s.name == "gc"]
+    assert gcs and gcs[-1].args["generation"] == 2
+    assert all(isinstance(s.args["collected"], int) for s in gcs)
+    assert all(s.trace_id == "t/c0" and s.parent_id == outer.span_id
+               and s.dur_s > 0 for s in gcs)
+    n = len(t.spans())
+    with t.use(outer):
+        gc.collect()                  # the hook went with disable()
+    assert len(t.spans()) == n
+
+
+def _traced_q8_delta_saves(tmp_path):
+    import jax.numpy as jnp
+
+    from repro.core import snapshot_pytree
+
+    rng = np.random.default_rng(3)
+    tree = {"w": jnp.asarray(rng.standard_normal((8, 256)), jnp.float32),
+            "b": jnp.asarray(rng.standard_normal(300), jnp.float32),
+            "n": jnp.asarray([7, 9], jnp.int32)}
+    c = ICheckCluster(n_icheck_nodes=2, trace=True,
+                      obs_dir=str(tmp_path / "obs"))
+    client = ICheckClient("app", c.controller, ranks=1,
+                          codec="q8-delta").init()
+    snaps = []
+    for step in range(2):
+        snap = snapshot_pytree(tree, step=step, codec="q8-delta",
+                               chain_lookup=client.delta_chain_lookup,
+                               tracer=c.tracer)
+        client.commit_snapshot(snap, blocking=True, drain=False)
+        snaps.append(snap)
+        # AdamW-like churn: every value moves, so every block changes
+        tree = {k: (v * 1.5 + 0.25 if v.dtype == jnp.float32 else v)
+                for k, v in tree.items()}
+    return c, client, snaps
+
+
+def test_traced_q8_delta_save_is_one_connected_tree(tmp_path):
+    c, client, snaps = _traced_q8_delta_saves(tmp_path)
+    try:
+        _assert_all_connected(c.tracer)
+        tid = trace_id_for("app", 1)
+        spans = c.tracer.spans(tid)
+        by_id = {s.span_id: s for s in spans}
+        names = {s.name for s in spans}
+        assert {"commit", "commit/catalog", "encode", "l1_commit",
+                "agent_put", "snapshot", "snapshot/encode",
+                "snapshot/d2h_wait", "snapshot/xor",
+                "snapshot/frame"} <= names, names
+        (root,) = [s for s in spans if s.parent_id is None]
+        assert root.name == "commit" and root.args["ckpt"] == 1
+        (snap,) = [s for s in spans if s.name == "snapshot"]
+        assert snap.parent_id == root.span_id
+        assert snap.args == {"step": 1, "codec": "q8-delta", "regions": 3}
+        for s in spans:
+            if s.name.startswith("snapshot/"):
+                assert by_id[s.parent_id].name == "snapshot"
+                assert snap.t0 <= s.t0 and s.t0 + s.dur_s <= \
+                    snap.t0 + snap.dur_s
+        # the frames the spans report are the frames that were shipped
+        shipped = c.controller.app("app").checkpoints[1].regions
+        frames = {s.args["region"]: s.args for s in spans
+                  if s.name == "snapshot/frame"}
+        assert set(frames) == {"w", "b"}
+        for name, args in frames.items():
+            assert args["frame"] == shipped[name].frame == "key"
+            assert args["frame"] == snaps[1].regions[name].encoded.frame
+            assert args["delta_built"] and args["delta_discarded"]
+            assert args["bytes"] == sum(
+                len(b) for b in snaps[1].regions[name].encoded.blobs.values())
+        puts = [s for s in spans if s.name == "agent_put"]
+        assert puts and all(s.args["bytes"] > 0 for s in puts)
+        d2h = [s for s in spans if s.name == "snapshot/d2h_wait"]
+        assert {s.args["region"] for s in d2h} == {"w", "b", "n"}
+        assert all(s.args["bytes"] > 0 for s in d2h)
+        # the encode time the codec gauge reads is the spans' own time
+        enc = snaps[1].regions["w"].encoded.encode_s
+        phases = sum(s.dur_s for s in spans if s.name.startswith(
+            "snapshot/") and s.args["region"] == "w")
+        assert enc == pytest.approx(phases, rel=1e-9)
+        # the first save is a keyframe: nothing built, nothing discarded
+        first = [s.args for s in c.tracer.spans(trace_id_for("app", 0))
+                 if s.name == "snapshot/frame"]
+        assert first and not any(a["delta_built"] for a in first)
+        assert c.tracer.to_chrome_trace()["otherData"]["clock"] == "wall"
+    finally:
+        client.finalize()
+        c.close()
+
+
+def test_traced_resume_gives_trainer_init_and_restore_spans():
+    from repro.configs import get_config
+    from repro.configs.base import ShapeConfig
+    from repro.train import ElasticTrainer
+
+    cfg = get_config("qwen2.5-3b", tiny=True)
+    shape = ShapeConfig("t", "train", 32, 4)
+    with ICheckCluster(n_icheck_nodes=2, trace=True) as c:
+        kw = dict(app_id="app", seed=3, commit_every=0, probe_every=0,
+                  total_steps=4)
+        t1 = ElasticTrainer(cfg, shape, c, **kw)
+        assert not t1.restarted
+        t1.commit(blocking=True)
+        t2 = ElasticTrainer(cfg, shape, c, **kw)
+        assert t2.restarted
+        t2.finalize()
+        c.controller.wait_for_drains(timeout=60)   # no span left open
+        spans = c.tracer.spans()
+        _assert_all_connected(c.tracer)
+    mine = [s for s in spans if s.trace_id == t2.trace_id]
+    names = {s.name for s in mine}
+    assert {"trainer_init", "trainer_init/state", "trainer_init/register",
+            "restart", "snapshot"} <= names, names
+    (init,) = [s for s in mine if s.name == "trainer_init"]
+    (restart,) = [s for s in mine if s.name == "restart"]
+    assert init.t0 + init.dur_s <= restart.t0
+    reg = [s for s in mine if s.name == "trainer_init/register"][0]
+    assert reg.args["bytes"] > 0
+    ckpt = [s for s in spans if s.trace_id == trace_id_for("app", 0)]
+    got = {s.name for s in ckpt}
+    assert {"restore", "restore/fetch", "restore/decode", "restore/place",
+            "restore/assemble", "restore/h2d"} <= got, got
+    place = [s for s in ckpt if s.name == "restore/place"][-1]
+    for s in ckpt:
+        if s.name in ("restore/assemble", "restore/h2d") \
+                and s.t0 >= restart.t0:
+            assert s.parent_id == place.span_id and s.args["bytes"] > 0
+    for s in ckpt:
+        if s.name in ("restore/place", "restore") and s.t0 >= restart.t0:
+            assert restart.t0 <= s.t0
+            assert s.t0 + s.dur_s <= restart.t0 + restart.dur_s
+
+
+def test_profiler_events_share_the_collector_clock(tmp_path):
+    """Under ``jax.profiler``, each ``icheck/`` host event of the
+    ``.xplane.pb`` lasts as long as its collector span, at one constant
+    offset between the two clocks."""
+    import glob
+    import time
+
+    import jax
+    from jax.profiler import ProfileData
+
+    t = TraceCollector(enabled=False)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        t.enable(annotate=True)
+        for i, pause in enumerate((0.004, 0.012, 0.002, 0.02)):
+            with t.span(f"outer{i}", "t/c0", "trk"):
+                time.sleep(pause)
+                with t.span(f"inner{i}", "t/c0", "trk"):
+                    time.sleep(pause / 2)
+            time.sleep(0.003)
+        t.disable()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("icheck/"):
+                    events[e.name[len("icheck/"):]] = e
+    spans = {s.name: s for s in t.spans() if s.name != "gc"}
+    assert set(spans) <= set(events) and len(spans) == 8
+    offsets = []
+    for name, s in spans.items():
+        e = events[name]
+        assert abs(e.duration_ns * 1e-9 - s.dur_s) < 1e-3, name
+        offsets.append(e.start_ns * 1e-9 - s.t0)
+    assert max(offsets) - min(offsets) < 1e-3
+
+
+def test_provisional_traces_under_thread_contention():
+    """Many threads at once, each a commit-shaped flow — a snapshot under a
+    provisional trace, then a root span whose trace is named mid-span and
+    adopts the snapshot — with garbage collections forced among them:
+    every trace comes out whole, connected and named."""
+    import gc
+    import sys
+    import threading
+
+    t = TraceCollector(enabled=True)
+    n_threads, rounds = 16, 20
+    errors = []
+
+    def flow(k):
+        try:
+            for r in range(rounds):
+                snap_tid = t.provisional("snap")
+                with t.span("snapshot", snap_tid, "trk", root=True) as snap:
+                    with t.span("snapshot/frame", snap_tid, "trk"):
+                        if r % 5 == 0:
+                            gc.collect(0)
+                prov = t.provisional("app")
+                with t.span("commit", prov, "trk", root=True):
+                    with t.span("commit/catalog", prov, "trk"):
+                        pass
+                    tid = trace_id_for(f"a{k}", r)
+                    root = t.adopt(prov, tid)
+                    t.adopt(snap.trace_id, tid, parent=root)
+                    with t.span("encode", tid, "trk"):
+                        t.note(n=r)
+        except Exception as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=flow, args=(k,))
+                   for k in range(n_threads)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+        assert not any(th.is_alive() for th in threads)
+    finally:
+        sys.setswitchinterval(old)
+        t.disable()
+    assert not errors, errors
+    assert not any("pending" in tid for tid in t.trace_ids())
+    for k in range(n_threads):
+        for r in range(rounds):
+            tid = trace_id_for(f"a{k}", r)
+            _assert_connected(t, tid)
+            names = sorted(s.name for s in t.spans(tid) if s.name != "gc")
+            assert names == ["commit", "commit/catalog", "encode",
+                             "snapshot", "snapshot/frame"], (tid, names)
