@@ -11,16 +11,21 @@ With ``codec="q8"`` / ``codec="q8-delta"`` the encode runs **on device**
 before the D2H copy: each float region part goes through
 ``kernels/ckpt_codec.quantize`` (or ``quantize_delta`` against the
 catalog's previous-codes state from ``chain_lookup``), so the host pulls
-int8 codes + 1/256 overhead of f32 scales — ~4x fewer D2H bytes than the
-raw f32 leaves — and the resulting :class:`~repro.core.tiers.EncodedRegion`
-frames travel the client→agent fabric and the storage tiers as-is
-(``ICheckClient.commit_snapshot``).
+the new int8 codes + 1/256 overhead of f32 scales — ~4x fewer D2H bytes
+than the raw f32 leaves.  For a delta the host then counts the blocks that
+changed against the previous codes, decides the frame from that count
+(sparse deltas only when they are smaller than keyframes) and builds only
+the frame that ships; the resulting
+:class:`~repro.core.tiers.EncodedRegion` frames travel the client→agent
+fabric and the storage tiers as-is (``ICheckClient.commit_snapshot``).
 
 Given a ``tracer`` (the cluster's :class:`~repro.obs.TraceCollector`), a
 snapshot is a ``snapshot`` span with one child per phase and region —
 ``snapshot/encode`` (kernel launch and async copy), ``snapshot/d2h_wait``,
-``snapshot/xor``, ``snapshot/frame`` — and a restore's placement is
-``restore/assemble`` and ``restore/h2d`` per leaf.
+``snapshot/changed`` (the changed-block count of a delta region),
+``snapshot/xor`` (the rows of a delta that ships), ``snapshot/frame`` —
+and a restore's placement is ``restore/assemble`` and ``restore/h2d`` per
+leaf.
 """
 from __future__ import annotations
 
@@ -33,6 +38,7 @@ from . import plan as planlib
 from ..kernels.ckpt_codec.blocks import BLOCK
 from ..obs import TraceCollector
 from .tiers import (DeltaState, EncodedRegion, is_float_dtype, pack_q8_region,
+                    q8_changed_blocks, q8_delta_kept, q8_delta_rows,
                     q8_pack_full)
 from .types import PartitionDesc, PartitionScheme, RegionMeta
 
@@ -201,18 +207,17 @@ def _snapshot_leaves(flat, codec: str, chain_lookup, impl: Optional[str],
                         prev_q = prev[p].codes_dev
                         if prev_q is None:
                             prev_q = prev[p].codes
-                        d, s, q = quantize_delta(a, prev_q, impl=impl)
-                        # the dense int8 XOR delta + scales cross D2H (~1/4
-                        # of the f32 bytes; sparsification happens
-                        # host-side); the new full codes q stay
-                        # device-resident for the next commit so nothing is
-                        # uploaded back
-                        outs[p] = (d, s, q)
+                        _, s, q = quantize_delta(a, prev_q, impl=impl)
                     else:
                         q, s = quantize(a, impl=impl)
-                        outs[p] = (q, s, q)
-                for d_or_q, s, _ in outs.values():
-                    for out in (d_or_q, s):
+                    # the new full codes + scales cross D2H (~1/4 of the
+                    # f32 bytes; which blocks changed is counted host-side
+                    # against the previous codes); q also stays
+                    # device-resident for the next commit, so nothing is
+                    # uploaded back
+                    outs[p] = (q, s)
+                for q, s in outs.values():
+                    for out in (q, s):
                         if hasattr(out, "copy_to_host_async"):
                             out.copy_to_host_async()
             work[name] = {"boxes": boxes, "desc": desc, "sizes": sizes,
@@ -245,34 +250,34 @@ def _snapshot_leaves(flat, codec: str, chain_lookup, impl: Optional[str],
 def _gather_encoded(name: str, leaf, codec: str, w: dict,
                     tracer: TraceCollector, trace_id: Optional[str]
                     ) -> SnapshotRegion:
-    """Finish one device-encoded region: D2H the codes/scales, reconstruct
-    codes from deltas (host XOR), frame via the shared packer."""
+    """Finish one device-encoded region: D2H the codes/scales, count the
+    blocks that changed against the previous codes, XOR only the rows of a
+    delta that ships, and frame via the shared packer."""
     prev: Optional[Dict[int, DeltaState]] = w["prev"]
     with tracer.timed("snapshot/d2h_wait", trace_id, TRACK,
                       region=name) as wait:
-        host = {p: (np.asarray(d_or_q),
-                    np.asarray(s).astype(np.float32, copy=False))
-                for p, (d_or_q, s, _) in w["outs"].items()}
-        tracer.note(bytes=sum(a.nbytes + s.nbytes
-                              for a, s in host.values()))
+        qparts = {p: (w["sizes"][p], np.asarray(q),
+                      np.asarray(s).astype(np.float32, copy=False))
+                  for p, (q, s) in w["outs"].items()}
+        tracer.note(bytes=sum(q.nbytes + s.nbytes
+                              for _, q, s in qparts.values()))
     seconds = w["launch_s"] + wait.seconds
-    qparts: Dict[int, Tuple[int, np.ndarray, np.ndarray]] = {}
-    dense_deltas: Dict[int, np.ndarray] = {}
+    changed = rows = None
     if prev is not None:
-        # the kernel shipped the XOR delta; reconstruct the full codes
-        # from the host-side previous codes (one int8 XOR — the packer
-        # reuses the dense delta instead of re-deriving it)
-        with tracer.timed("snapshot/xor", trace_id, TRACK,
-                          region=name) as xor:
-            for p, (a, scales) in host.items():
-                qparts[p] = (w["sizes"][p], np.bitwise_xor(prev[p].codes, a),
-                             scales)
-                dense_deltas[p] = a
-            tracer.note(bytes=sum(a.nbytes for a in dense_deltas.values()))
-        seconds += xor.seconds
-    else:
-        qparts = {p: (w["sizes"][p], a, scales)
-                  for p, (a, scales) in host.items()}
+        # which blocks changed decides the frame before any is built
+        with tracer.timed("snapshot/changed", trace_id, TRACK,
+                          region=name) as counting:
+            changed = q8_changed_blocks(qparts, prev)
+            tracer.note(bytes=sum(q.nbytes + s.nbytes
+                                  for _, q, s in qparts.values()))
+        seconds += counting.seconds
+        if q8_delta_kept(qparts, changed):
+            with tracer.timed("snapshot/xor", trace_id, TRACK,
+                              region=name) as xor:
+                rows = {p: q8_delta_rows(q, prev[p], changed[p])
+                        for p, (_, q, _) in qparts.items()}
+                tracer.note(bytes=sum(r.nbytes for r in rows.values()))
+            seconds += xor.seconds
     np_dtype = getattr(leaf, "dtype", None)
     np_dtype = np.dtype(np_dtype) if np_dtype is not None \
         else np.asarray(leaf).dtype
@@ -280,13 +285,15 @@ def _gather_encoded(name: str, leaf, codec: str, w: dict,
     with tracer.timed("snapshot/frame", trace_id, TRACK,
                       region=name) as framing:
         if codec == "q8-delta":
-            packed: Dict[str, bool] = {}
+            packed: Dict[str, Any] = {}
             blobs, states, frame = pack_q8_region(
-                qparts, prev, deltas=dense_deltas or None, info=packed)
+                qparts, prev, changed=changed, rows=rows, info=packed)
             built = packed["delta_built"]
-            tracer.note(frame=frame, delta_built=built,
+            tracer.note(frame=frame, blocks=packed["blocks"],
+                        changed_blocks=packed["changed_blocks"],
+                        delta_built=built,
                         delta_discarded=built and frame == "key")
-            for p, (_, _, q_dev) in w["outs"].items():
+            for p, (q_dev, _) in w["outs"].items():
                 states[p].codes_dev = q_dev
         else:
             blobs = {p: q8_pack_full(n, codes, scales)

@@ -143,34 +143,61 @@ def resolve_codec(codec: str,
 
 def q8_pack_full(n: int, codes: np.ndarray, scales: np.ndarray,
                  mode: bytes = _Q8_QUANT) -> bytes:
-    """Pack a full q8 frame (plain ``Q`` or chain keyframe ``K``)."""
-    return (mode + int(n).to_bytes(8, "little")
-            + np.ascontiguousarray(scales, np.float32).tobytes()
-            + np.ascontiguousarray(codes, np.int8).tobytes())
+    """Pack a full q8 frame (plain ``Q`` or chain keyframe ``K``), copying
+    the scales and codes once."""
+    return b"".join((mode, int(n).to_bytes(8, "little"),
+                     memoryview(np.ascontiguousarray(scales, np.float32)),
+                     memoryview(np.ascontiguousarray(codes, np.int8))))
 
 
 def _q8_full_size(nb: int) -> int:
     return 9 + 4 * nb + _Q8_BLOCK * nb
 
 
-def q8_pack_delta(n: int, codes: np.ndarray, scales: np.ndarray,
-                  prev: DeltaState,
-                  delta: Optional[np.ndarray] = None) -> Optional[bytes]:
-    """Sparse XOR-delta frame against ``prev``; None when shapes mismatch
-    (the caller must fall back to a keyframe).  ``delta`` short-circuits
-    the XOR when the caller already holds it (the device kernel's output).
-    """
-    if prev.n != n or prev.codes.shape != codes.shape:
-        return None
-    if delta is None:
-        delta = np.bitwise_xor(codes, prev.codes)
-    changed = np.logical_or((delta != 0).any(axis=1),
-                            (scales != prev.scales).any(axis=1))
-    idx = np.flatnonzero(changed).astype(np.uint32)
-    return (_Q8_DELTA + int(n).to_bytes(8, "little")
-            + len(idx).to_bytes(4, "little") + idx.tobytes()
-            + np.ascontiguousarray(scales[idx], np.float32).tobytes()
-            + np.ascontiguousarray(delta[idx], np.int8).tobytes())
+def _q8_delta_size(nnz: int) -> int:
+    return 13 + (4 + 4 + _Q8_BLOCK) * nnz
+
+
+# blocks per step of the changed-block compare: one 128 KiB scratch, reused
+_CMP_ROWS = 4096
+
+
+def _changed_mask(codes: np.ndarray, scales: np.ndarray,
+                  prev: DeltaState) -> np.ndarray:
+    """(nb,) bool: the blocks whose codes or scale differ from ``prev``.
+
+    Reads both code arrays once, as (nb, BLOCK/8) u64 rows a chunk at a
+    time, and writes nothing of the codes' size."""
+    new = np.ascontiguousarray(codes, np.int8).view(np.uint64)
+    old = np.ascontiguousarray(prev.codes, np.int8).view(np.uint64)
+    nb = new.shape[0]
+    mask = np.empty(nb, bool)
+    ne = np.empty((min(nb, _CMP_ROWS), new.shape[1]), bool)
+    for lo in range(0, nb, _CMP_ROWS):
+        hi = min(lo + _CMP_ROWS, nb)
+        np.not_equal(new[lo:hi], old[lo:hi], out=ne[:hi - lo])
+        np.any(ne[:hi - lo], axis=1, out=mask[lo:hi])
+    mask |= (scales != prev.scales).any(axis=1)
+    return mask
+
+
+def q8_delta_rows(codes: np.ndarray, prev: DeltaState,
+                  idx: np.ndarray) -> np.ndarray:
+    """The XOR of the new and the previous codes, on the blocks ``idx``."""
+    rows = np.asarray(codes)[idx]
+    np.bitwise_xor(rows, prev.codes[idx], out=rows)
+    return rows
+
+
+def _q8_pack_delta_rows(n: int, idx: np.ndarray, scales: np.ndarray,
+                        rows: np.ndarray) -> bytes:
+    """A sparse delta frame from its block indices, the part's new scales
+    and the XOR rows of those blocks, copied once."""
+    return b"".join((_Q8_DELTA, int(n).to_bytes(8, "little"),
+                     len(idx).to_bytes(4, "little"), memoryview(idx),
+                     memoryview(np.ascontiguousarray(scales[idx],
+                                                     np.float32)),
+                     memoryview(np.ascontiguousarray(rows, np.int8))))
 
 
 def _q8_unpack_full(blob: bytes) -> Tuple[int, np.ndarray, np.ndarray]:
@@ -251,9 +278,41 @@ def q8_quantize_part(data: bytes, dtype: str) -> Tuple[int, np.ndarray,
     return n, codes, scales
 
 
+def q8_changed_blocks(parts: Dict[int, Tuple[int, np.ndarray, np.ndarray]],
+                      prev: Optional[Dict[int, DeltaState]]
+                      ) -> Optional[Dict[int, np.ndarray]]:
+    """Per part, the indices (u32) of the blocks whose codes or scale
+    differ from ``prev``: the blocks a sparse delta frame carries.
+
+    None when the region cannot be delta-framed: no previous state for
+    exactly these parts, or a part whose size or code shape no longer
+    matches.  A read-only pass over the codes.
+    """
+    if prev is None or set(prev) != set(parts):
+        return None
+    for p, (n, codes, _) in parts.items():
+        if prev[p].n != n or prev[p].codes.shape != codes.shape:
+            return None
+    return {p: np.flatnonzero(_changed_mask(codes, scales, prev[p]))
+            .astype(np.uint32)
+            for p, (_, codes, scales) in parts.items()}
+
+
+def q8_delta_kept(parts: Dict[int, Tuple[int, np.ndarray, np.ndarray]],
+                  changed: Optional[Dict[int, np.ndarray]]) -> bool:
+    """The keyframe rule, from the changed-block counts alone: delta frames
+    ship iff their total size is strictly below the keyframes' total."""
+    if changed is None:
+        return False
+    return (sum(_q8_delta_size(len(idx)) for idx in changed.values())
+            < sum(_q8_full_size(codes.shape[0])
+                  for _, codes, _ in parts.values()))
+
+
 def pack_q8_region(parts: Dict[int, Tuple[int, np.ndarray, np.ndarray]],
                    prev: Optional[Dict[int, DeltaState]],
-                   deltas: Optional[Dict[int, np.ndarray]] = None,
+                   changed: Optional[Dict[int, np.ndarray]] = None,
+                   rows: Optional[Dict[int, np.ndarray]] = None,
                    info: Optional[dict] = None
                    ) -> Tuple[Dict[int, bytes], Dict[int, DeltaState], str]:
     """Frame one region's quantized parts as deltas or keyframes.
@@ -261,32 +320,35 @@ def pack_q8_region(parts: Dict[int, Tuple[int, np.ndarray, np.ndarray]],
     ``parts[part] = (n, codes, scales)`` — produced host-side by
     :func:`q8_quantize_part` or device-side by the ``kernels/ckpt_codec``
     Pallas ops (both paths share this packer, so framing policy cannot
-    drift).  Emits sparse deltas against ``prev`` when the whole region has
-    matching previous-codes state **and** the delta frames are actually
-    smaller than keyframes (high-churn commits fall back to a keyframe, so
-    q8-delta never loses to plain q8); returns ``(blobs, new_states,
-    frame)`` with frame ``"key"`` or ``"delta"``.  ``info["delta_built"]``
-    (when given) says whether delta frames were built, kept or not.
+    drift).  The frame is decided before any is built: the changed blocks
+    are counted against ``prev`` (:func:`q8_changed_blocks`, unless the
+    caller passes ``changed``), and sparse deltas ship when the whole
+    region has matching previous-codes state **and** they are smaller than
+    keyframes (:func:`q8_delta_kept`; high-churn commits go out as
+    keyframes, so q8-delta never loses to plain q8).  Only the frame that
+    ships is built, copying its payload once; ``rows`` may carry the kept
+    deltas' XOR rows (:func:`q8_delta_rows`).  Returns ``(blobs,
+    new_states, frame)`` with frame ``"key"`` or ``"delta"``.  ``info``
+    (when given) receives ``blocks``, ``changed_blocks`` (None without a
+    usable ``prev``) and ``delta_built``.
     """
+    if changed is None:
+        changed = q8_changed_blocks(parts, prev)
     states = {p: DeltaState(n=n, codes=codes, scales=scales)
               for p, (n, codes, scales) in parts.items()}
+    kept = q8_delta_kept(parts, changed)
     if info is not None:
-        info["delta_built"] = False
-    if prev is not None and set(prev) == set(parts):
-        delta_blobs: Dict[int, bytes] = {}
-        for p, (n, codes, scales) in parts.items():
-            blob = q8_pack_delta(n, codes, scales, prev[p],
-                                 delta=(deltas or {}).get(p))
-            if blob is None:
-                break
-            delta_blobs[p] = blob
-        if len(delta_blobs) == len(parts):
-            if info is not None:
-                info["delta_built"] = True
-            key_total = sum(_q8_full_size(codes.shape[0])
-                            for _, codes, _ in parts.values())
-            if sum(len(b) for b in delta_blobs.values()) < key_total:
-                return delta_blobs, states, "delta"
+        info["blocks"] = sum(codes.shape[0] for _, codes, _ in parts.values())
+        info["changed_blocks"] = None if changed is None \
+            else sum(len(idx) for idx in changed.values())
+        info["delta_built"] = kept
+    if kept:
+        if rows is None:
+            rows = {p: q8_delta_rows(codes, prev[p], changed[p])
+                    for p, (_, codes, _) in parts.items()}
+        blobs = {p: _q8_pack_delta_rows(n, changed[p], scales, rows[p])
+                 for p, (n, _, scales) in parts.items()}
+        return blobs, states, "delta"
     keys = {p: q8_pack_full(n, codes, scales, _Q8_KEY)
             for p, (n, codes, scales) in parts.items()}
     return keys, states, "key"

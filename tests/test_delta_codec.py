@@ -106,7 +106,7 @@ def test_chain_replay_matches_undelta_dequantize():
     pytest.importorskip("jax")
     import jax.numpy as jnp
 
-    from repro.core.tiers import DeltaState, q8_pack_delta, q8_pack_full
+    from repro.core.tiers import DeltaState, pack_q8_region, q8_pack_full
     from repro.kernels.ckpt_codec import quantize, undelta_dequantize
     from repro.kernels.ckpt_codec.blocks import BLOCK
 
@@ -118,7 +118,10 @@ def test_chain_replay_matches_undelta_dequantize():
     q0, s0 = (np.asarray(v) for v in quantize(x0, impl="xla"))
     q1, s1 = (np.asarray(v) for v in quantize(x1, impl="xla"))
     key = q8_pack_full(n, q0, s0, b"K")
-    delta = q8_pack_delta(n, q1, s1, DeltaState(n=n, codes=q0, scales=s0))
+    blobs, _, frame = pack_q8_region(
+        {0: (n, q1, s1)}, {0: DeltaState(n=n, codes=q0, scales=s0)})
+    assert frame == "delta"
+    delta = blobs[0]
     host = np.frombuffer(q8_chain_decode([key, delta], "float32"),
                          np.float32)
     dense_delta = np.bitwise_xor(q1, q0)
@@ -126,6 +129,141 @@ def test_chain_replay_matches_undelta_dequantize():
         jnp.asarray(dense_delta), jnp.asarray(q0), jnp.asarray(s1), (n,),
         jnp.float32, impl="xla"))
     np.testing.assert_array_equal(host, device)
+
+
+# The packer as it stood before it counted changed blocks first: it built
+# every delta frame, compared sizes and threw the loser away.  Kept
+# verbatim as the oracle the counting packer must match byte for byte.
+_REF_Q8_QUANT = b"Q"
+_REF_Q8_KEY = b"K"
+_REF_Q8_DELTA = b"D"
+
+
+def _ref_q8_pack_full(n, codes, scales, mode=_REF_Q8_QUANT):
+    return (mode + int(n).to_bytes(8, "little")
+            + np.ascontiguousarray(scales, np.float32).tobytes()
+            + np.ascontiguousarray(codes, np.int8).tobytes())
+
+
+def _ref_q8_full_size(nb):
+    return 9 + 4 * nb + 256 * nb
+
+
+def _ref_q8_pack_delta(n, codes, scales, prev, delta=None):
+    if prev.n != n or prev.codes.shape != codes.shape:
+        return None
+    if delta is None:
+        delta = np.bitwise_xor(codes, prev.codes)
+    changed = np.logical_or((delta != 0).any(axis=1),
+                            (scales != prev.scales).any(axis=1))
+    idx = np.flatnonzero(changed).astype(np.uint32)
+    return (_REF_Q8_DELTA + int(n).to_bytes(8, "little")
+            + len(idx).to_bytes(4, "little") + idx.tobytes()
+            + np.ascontiguousarray(scales[idx], np.float32).tobytes()
+            + np.ascontiguousarray(delta[idx], np.int8).tobytes())
+
+
+def _ref_pack_q8_region(parts, prev, deltas=None, info=None):
+    from repro.core.tiers import DeltaState
+
+    states = {p: DeltaState(n=n, codes=codes, scales=scales)
+              for p, (n, codes, scales) in parts.items()}
+    if info is not None:
+        info["delta_built"] = False
+    if prev is not None and set(prev) == set(parts):
+        delta_blobs = {}
+        for p, (n, codes, scales) in parts.items():
+            blob = _ref_q8_pack_delta(n, codes, scales, prev[p],
+                                      delta=(deltas or {}).get(p))
+            if blob is None:
+                break
+            delta_blobs[p] = blob
+        if len(delta_blobs) == len(parts):
+            if info is not None:
+                info["delta_built"] = True
+            key_total = sum(_ref_q8_full_size(codes.shape[0])
+                            for _, codes, _ in parts.values())
+            if sum(len(b) for b in delta_blobs.values()) < key_total:
+                return delta_blobs, states, "delta"
+    keys = {p: _ref_q8_pack_full(n, codes, scales, _REF_Q8_KEY)
+            for p, (n, codes, scales) in parts.items()}
+    return keys, states, "key"
+
+
+_NB = 100                       # blocks of the churned part; its last is
+_N = _NB * 256 - 37             # padded
+# the last changed-block count whose delta (13 + 264 nnz bytes) is still
+# strictly smaller than the keyframe (9 + 260 nb bytes), and the first not
+_LAST_DELTA = max(k for k in range(_NB + 1)
+                  if 13 + 264 * k < 9 + 260 * _NB)
+
+
+def _churn(x, blocks, how="add"):
+    """Change the given 256-value blocks of ``x``: ``add`` moves a value
+    (its codes change), ``double`` scales the block by 2 (same codes, a new
+    scale)."""
+    y = x.copy()
+    for b in blocks:
+        if how == "add":
+            y[b * 256] += 3.0
+        else:
+            y[b * 256:(b + 1) * 256] *= 2
+    return y
+
+
+@pytest.mark.parametrize("case,changed,frame", [
+    ("zero", 0, "delta"),
+    ("one", 1, "delta"),
+    ("half", _NB // 2, "delta"),
+    ("last_delta", _LAST_DELTA, "delta"),
+    ("first_key", _LAST_DELTA + 1, "key"),
+    ("all", _NB, "key"),
+    ("scale_only", _NB // 2, "delta"),
+    ("part_mismatch", None, "key"),
+])
+def test_counting_packer_matches_reference_packer(case, changed, frame):
+    """Counting changed blocks first and building only the frame that
+    ships gives the reference packer's blobs, states and frame at every
+    churn level, through the packer and through the host encode."""
+    from repro.core.tiers import pack_q8_region, q8_quantize_part
+
+    assert 13 + 264 * _LAST_DELTA < 9 + 260 * _NB \
+        <= 13 + 264 * (_LAST_DELTA + 1)
+    rng = np.random.default_rng(21)
+    x0 = {0: rng.standard_normal(_N).astype(np.float32)}
+    if case == "part_mismatch":
+        # a second part, another size than in the previous commit
+        x0[1] = rng.standard_normal(700).astype(np.float32)
+        x1 = {0: _churn(x0[0], [3]),
+              1: rng.standard_normal(1200).astype(np.float32)}
+    else:
+        x1 = {0: _churn(x0[0], range(changed),
+                        "double" if case == "scale_only" else "add")}
+    _, prev, _ = encode_delta_region({p: x.tobytes() for p, x in x0.items()},
+                                     "float32", None)
+    parts = {p: q8_quantize_part(x.tobytes(), "float32")
+             for p, x in x1.items()}
+    if case == "scale_only":
+        np.testing.assert_array_equal(parts[0][1], prev[0].codes)
+        assert (parts[0][2] != prev[0].scales).sum() == changed
+    want_blobs, want_states, want_frame = _ref_pack_q8_region(parts, prev)
+    assert want_frame == frame
+    info = {}
+    got = {"packer": pack_q8_region(parts, prev, info=info),
+           "host encode": encode_delta_region(
+               {p: x.tobytes() for p, x in x1.items()}, "float32", prev)}
+    for caller, (blobs, states, got_frame) in got.items():
+        assert got_frame == want_frame, caller
+        assert blobs == want_blobs, caller
+        assert all(type(b) is bytes for b in blobs.values())
+        assert set(states) == set(want_states)
+        for p, st in states.items():
+            assert st.n == want_states[p].n, caller
+            np.testing.assert_array_equal(st.codes, want_states[p].codes)
+            np.testing.assert_array_equal(st.scales, want_states[p].scales)
+    assert info["blocks"] == _NB + (5 if case == "part_mismatch" else 0)
+    assert info["changed_blocks"] == changed
+    assert info["delta_built"] == (frame == "delta")
 
 
 def test_shared_block_reference_matches_kernels():
@@ -468,6 +606,78 @@ def test_device_snapshot_delta_commit_and_restart(cluster):
     assert "icheck_codec_compression_ratio" in prom
     assert "icheck_codec_encode_seconds" in prom
     client.finalize()
+
+
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+def test_device_snapshot_frames_match_reference_packer(tmp_path, impl):
+    """Three device-encoded saves (a first keyframe, a full-churn save the
+    changed-block count sends out as keyframes, a low-churn save that keeps
+    deltas) ship exactly the reference packer's frames of the same codes,
+    restart bit-identical to the chain replay, and note the count on their
+    ``snapshot/frame``."""
+    jax = pytest.importorskip("jax")
+    jnp = jax.numpy
+    from repro.core import snapshot_pytree
+    from repro.kernels.ckpt_codec import quantize
+    from repro.obs import trace_id_for
+
+    rng = np.random.default_rng(13)
+    host = {"w": rng.standard_normal((40, 256)).astype(np.float32),
+            "b": rng.standard_normal(300).astype(np.float32)}
+    c = ICheckCluster(n_icheck_nodes=2, trace=True,
+                      obs_dir=str(tmp_path / "obs"))
+    client = ICheckClient("app", c.controller, ranks=1,
+                          codec="q8-delta").init()
+    try:
+        chain = {name: None for name in host}
+        sent = {name: [] for name in host}
+        for step, churn in enumerate(["first", "full", "low"]):
+            if churn == "full":                  # every value moves
+                host = {k: v * 1.5 + 0.25 for k, v in host.items()}
+            elif churn == "low":                 # one block of w moves
+                host["w"] = host["w"].copy()
+                host["w"][0, :4] += 1.0
+            tree = {k: jnp.asarray(v) for k, v in host.items()}
+            snap = snapshot_pytree(tree, step=step, codec="q8-delta",
+                                   chain_lookup=client.delta_chain_lookup,
+                                   impl=impl, tracer=c.tracer)
+            client.commit_snapshot(snap, blocking=True, drain=False)
+            frames = {s.args["region"]: s.args
+                      for s in c.tracer.spans(trace_id_for("app", step))
+                      if s.name == "snapshot/frame"}
+            for name, x in host.items():
+                q, sc = (np.asarray(v) for v in quantize(tree[name],
+                                                         impl=impl))
+                blobs, chain[name], frame = _ref_pack_q8_region(
+                    {0: (x.size, q, sc)}, chain[name])
+                enc = snap.regions[name].encoded
+                assert enc.frame == frame
+                assert enc.blobs == blobs
+                sent[name] = [enc.blobs[0]] if frame == "key" \
+                    else sent[name] + [enc.blobs[0]]
+                args = frames[name]
+                assert args["frame"] == frame
+                assert args["blocks"] == chain[name][0].codes.shape[0]
+                assert args["delta_built"] == (frame == "delta")
+                assert not args["delta_discarded"]
+                if churn == "first":
+                    assert frame == "key" and args["changed_blocks"] is None
+                elif churn == "full":            # decided from the count
+                    assert frame == "key"
+                    assert args["changed_blocks"] == args["blocks"]
+                else:
+                    assert frame == "delta"
+                    assert args["changed_blocks"] == (name == "w")
+        meta, out, _ = client.restart()
+        assert meta.step == 2
+        for name in host:
+            assert meta.regions[name].chain == (1, 2)
+            want = np.frombuffer(q8_chain_decode(sent[name], "float32"),
+                                 np.float32)
+            np.testing.assert_array_equal(out[name][0].ravel(), want)
+    finally:
+        client.finalize()
+        c.close()
 
 
 def test_elastic_trainer_q8_delta_roundtrip():

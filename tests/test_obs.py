@@ -569,8 +569,10 @@ def test_traced_q8_delta_save_is_one_connected_tree(tmp_path):
         names = {s.name for s in spans}
         assert {"commit", "commit/catalog", "encode", "l1_commit",
                 "agent_put", "snapshot", "snapshot/encode",
-                "snapshot/d2h_wait", "snapshot/xor",
+                "snapshot/d2h_wait", "snapshot/changed",
                 "snapshot/frame"} <= names, names
+        # full churn: the count decides keyframes, so no delta rows are XORed
+        assert "snapshot/xor" not in names
         (root,) = [s for s in spans if s.parent_id is None]
         assert root.name == "commit" and root.args["ckpt"] == 1
         (snap,) = [s for s in spans if s.name == "snapshot"]
@@ -589,7 +591,8 @@ def test_traced_q8_delta_save_is_one_connected_tree(tmp_path):
         for name, args in frames.items():
             assert args["frame"] == shipped[name].frame == "key"
             assert args["frame"] == snaps[1].regions[name].encoded.frame
-            assert args["delta_built"] and args["delta_discarded"]
+            assert not args["delta_built"] and not args["delta_discarded"]
+            assert args["changed_blocks"] == args["blocks"] > 0
             assert args["bytes"] == sum(
                 len(b) for b in snaps[1].regions[name].encoded.blobs.values())
         puts = [s for s in spans if s.name == "agent_put"]
