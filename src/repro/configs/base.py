@@ -1,7 +1,7 @@
 """Model / run configuration system.
 
-``ModelConfig`` is a frozen dataclass describing one architecture; the ten
-assigned architectures each ship a module ``configs/<id>.py`` exposing
+``ModelConfig`` is a frozen dataclass describing one architecture; the
+architectures of ``ARCH_IDS`` each ship a module ``configs/<id>.py`` exposing
 ``CONFIG`` (full size) and ``tiny()`` (reduced same-family config for CPU
 smoke tests).  ``get_config(name)`` resolves either.
 
@@ -32,14 +32,27 @@ class ModelConfig:
     mixer: str = "attention"         # attention | rwkv6 | rglru_hybrid
     ffn: str = "swiglu"              # swiglu | geglu | moe | rwkv_cmix
     # --- MoE ---
-    num_experts: int = 0
+    num_experts: int = 0             # the router's width: every expert
     experts_per_token: int = 0
-    capacity_factor: float = 1.25
+    experts_held: int = 0            # experts this chip holds (0 -> all)
+    expert_offset: int = 0           # router index of the first one held
     moe_d_ff: int = 0                # per-expert hidden dim (0 -> d_ff)
-    router_aux_coef: float = 0.01
+    shared_experts: int = 0          # SwiGLUs of width moe_d_ff on every token
+    norm_topk_prob: bool = True      # renormalise the top-k router weights
+    router_aux_coef: float = 0.01    # per-sequence balance loss weight
+    first_dense_layers: int = 0      # leading layers with a dense d_ff FFN
     # --- attention ---
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    # multi-head latent attention (DeepSeek-V2) when kv_lora_rank > 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN RoPE scaling (arXiv:2309.00071) when rope_factor > 1
+    rope_factor: float = 1.0
+    rope_original_max_pos: int = 0
+    rope_mscale_all_dim: float = 0.0
     window: Optional[int] = None     # sliding window (local attention)
     logit_softcap: float = 0.0
     # --- hybrid (RG-LRU) ---
@@ -83,6 +96,14 @@ class ModelConfig:
         return self.moe_d_ff or self.d_ff
 
     @property
+    def resolved_experts_held(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
     def is_encdec(self) -> bool:
         return self.encoder_layers > 0
 
@@ -123,7 +144,7 @@ ALL_SHAPES = (TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K)
 ARCH_IDS = (
     "dbrx-132b", "qwen3-moe-235b-a22b", "seamless-m4t-medium", "yi-6b",
     "phi3-medium-14b", "deepseek-7b", "qwen2.5-3b", "pixtral-12b",
-    "rwkv6-7b", "recurrentgemma-9b",
+    "rwkv6-7b", "recurrentgemma-9b", "deepseek-v2-lite",
 )
 
 

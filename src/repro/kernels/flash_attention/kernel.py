@@ -6,7 +6,9 @@ Design (TPU-native, not a CUDA port):
   live in VMEM scratch and persist across the KV-block sweep for a fixed
   (b, h, iq); the output tile is written once, on the last KV block.
 
-  Tiles: q (bq, D), k/v (bk, D) staged HBM->VMEM by BlockSpec; the score
+  Tiles: q (bq, D), k (bk, D), v (bk, Dv) staged HBM->VMEM by BlockSpec
+  (Dv may differ from D, as in latent attention's 192-wide queries and
+  keys over 128-wide values); the score
   tile (bq, bk) hits the MXU via jnp.dot in f32.  bq = bk = 128 aligns every
   matmul operand to the 128x128 systolic array.  GQA is handled in the
   BlockSpec index_map (query head h reads KV head h // group), so KV tiles
@@ -100,9 +102,11 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            block_q: int = 128, block_k: int = 128,
                            interpret: bool = False,
                            return_lse: bool = False):
-    """q: (B, Hq, T, D), k/v: (B, Hkv, S, D) -> (B, Hq, T, D) [, lse]."""
+    """q, k: (B, Hq|Hkv, T|S, D), v: (B, Hkv, S, Dv) -> (B, Hq, T, Dv)
+    [, lse]."""
     b, hq, t, d = q.shape
     _, hkv, s, _ = k.shape
+    dv = v.shape[-1]
     assert hq % hkv == 0
     g = hq // hkv
     if scale is None:
@@ -124,19 +128,19 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
             pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
             pl.BlockSpec((1, 1, bk, d),
                          lambda b_, h, i, j, g=g: (b_, h // g, j, 0)),
-            pl.BlockSpec((1, 1, bk, d),
+            pl.BlockSpec((1, 1, bk, dv),
                          lambda b_, h, i, j, g=g: (b_, h // g, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, dv), lambda b_, h, i, j: (b_, h, i, 0)),
             pl.BlockSpec((1, 1, bq, LANES), lambda b_, h, i, j: (b_, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, hq, tp, d), q.dtype),
+            jax.ShapeDtypeStruct((b, hq, tp, dv), q.dtype),
             jax.ShapeDtypeStruct((b, hq, tp, LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
         ],

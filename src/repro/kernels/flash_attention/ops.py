@@ -33,6 +33,7 @@ def _blocked(q, k, v, bq, bk):
     """Pad + reshape to blocks. Returns (qb, kb, vb, dims)."""
     b, hq, t, d = q.shape
     _, hkv, s, _ = k.shape
+    dv = v.shape[-1]
     g = hq // hkv
     tp, sp = next_multiple(t, bq), next_multiple(s, bk)
     nq, nk = tp // bq, sp // bk
@@ -41,8 +42,8 @@ def _blocked(q, k, v, bq, bk):
     vf = jnp.pad(v.astype(jnp.float32), ((0, 0), (0, 0), (0, sp - s), (0, 0)))
     qb = qf.reshape(b, hkv, g, nq, bq, d).transpose(3, 0, 1, 2, 4, 5)
     kb = kf.reshape(b, hkv, nk, bk, d).transpose(2, 0, 1, 3, 4)
-    vb = vf.reshape(b, hkv, nk, bk, d).transpose(2, 0, 1, 3, 4)
-    return qb, kb, vb, (b, hq, hkv, g, t, s, tp, sp, nq, nk, d)
+    vb = vf.reshape(b, hkv, nk, bk, dv).transpose(2, 0, 1, 3, 4)
+    return qb, kb, vb, (b, hq, hkv, g, t, s, tp, sp, nq, nk, dv)
 
 
 def _xla_blockwise(q, k, v, *, causal, window, scale,
@@ -53,7 +54,7 @@ def _xla_blockwise(q, k, v, *, causal, window, scale,
     bq = min(block_q, next_multiple(t, 8))
     bk = min(block_k, next_multiple(s, 128))
     qb, kb, vb, dims = _blocked(q, k, v, bq, bk)
-    (_, _, hkv, g, _, _, tp, sp, nq, nk, _) = dims
+    (_, _, hkv, g, _, _, tp, sp, nq, nk, dv) = dims
     offset = s - t
 
     def q_block(carry, iq_and_q):
@@ -75,7 +76,7 @@ def _xla_blockwise(q, k, v, *, causal, window, scale,
 
         m0 = jnp.full((b, hkv, g, bq, 1), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, hkv, g, bq, 1), jnp.float32)
-        a0 = jnp.zeros((b, hkv, g, bq, d), jnp.float32)
+        a0 = jnp.zeros((b, hkv, g, bq, dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(
             kv_block, (m0, l0, a0), (jnp.arange(nk), kb, vb))
         lsafe = jnp.where(l == 0, 1.0, l)
@@ -83,7 +84,7 @@ def _xla_blockwise(q, k, v, *, causal, window, scale,
         return carry, (acc / lsafe, lse)
 
     _, (ob, lseb) = jax.lax.scan(q_block, None, (jnp.arange(nq), qb))
-    out = ob.transpose(1, 2, 3, 0, 4, 5).reshape(b, hq, tp, d)[:, :, :t, :]
+    out = ob.transpose(1, 2, 3, 0, 4, 5).reshape(b, hq, tp, dv)[:, :, :t, :]
     out = out.astype(q.dtype)
     if not return_lse:
         return out
@@ -100,11 +101,11 @@ def _xla_flash_bwd(q, k, v, o, lse, do, *, causal, window, scale,
     bq = min(block_q, next_multiple(t, 8))
     bk = min(block_k, next_multiple(s, 128))
     qb, kb, vb, dims = _blocked(q, k, v, bq, bk)
-    (_, _, hkv, g, _, _, tp, sp, nq, nk, _) = dims
+    (_, _, hkv, g, _, _, tp, sp, nq, nk, dv) = dims
     offset = s - t
     dof = jnp.pad(do.astype(jnp.float32),
                   ((0, 0), (0, 0), (0, tp - t), (0, 0)))
-    dob = dof.reshape(b, hkv, g, nq, bq, d).transpose(3, 0, 1, 2, 4, 5)
+    dob = dof.reshape(b, hkv, g, nq, bq, dv).transpose(3, 0, 1, 2, 4, 5)
     of = jnp.pad(o.astype(jnp.float32),
                  ((0, 0), (0, 0), (0, tp - t), (0, 0)))
     # D_i = rowsum(do * o)
@@ -141,13 +142,13 @@ def _xla_flash_bwd(q, k, v, o, lse, do, *, causal, window, scale,
         return (dk_acc, dv_acc), dq_t
 
     dk0 = jnp.zeros((nk, b, hkv, bk, d), jnp.float32)
-    dv0 = jnp.zeros((nk, b, hkv, bk, d), jnp.float32)
+    dv0 = jnp.zeros((nk, b, hkv, bk, dv), jnp.float32)
     (dkb, dvb), dqb = jax.lax.scan(
         q_block, (dk0, dv0), (jnp.arange(nq), qb, dob, Db, lseb))
     dq = dqb.transpose(1, 2, 3, 0, 4, 5).reshape(b, hq, tp, d)[:, :, :t, :]
     dk = dkb.transpose(1, 2, 0, 3, 4).reshape(b, hkv, sp, d)[:, :, :s, :]
-    dv = dvb.transpose(1, 2, 0, 3, 4).reshape(b, hkv, sp, d)[:, :, :s, :]
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    dvv = dvb.transpose(1, 2, 0, 3, 4).reshape(b, hkv, sp, dv)[:, :, :s, :]
+    return dq.astype(q.dtype), dk.astype(k.dtype), dvv.astype(v.dtype)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -191,7 +192,8 @@ _attention_core.defvjp(_attention_fwd, _attention_bwd)
 def attention(q, k, v, *, causal: bool = True, window: int | None = None,
               scale: float | None = None, impl: str | None = None,
               block_q: int = 128, block_k: int = 128):
-    """Flash attention. q: (B, Hq, T, D), k/v: (B, Hkv, S, D)."""
+    """Flash attention. q: (B, Hq, T, D), k: (B, Hkv, S, D), v: (B, Hkv,
+    S, Dv) -> (B, Hq, T, Dv); the scale defaults to D**-0.5."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     impl = resolve_impl(impl)

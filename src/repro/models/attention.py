@@ -106,6 +106,7 @@ def _project_qkv(params, x, xkv, num_heads, num_kv_heads, head_dim):
 
 def sharded_flash_attention(q, k, v, *, causal: bool = True,
                             window: Optional[int] = None,
+                            scale: Optional[float] = None,
                             impl: Optional[str] = None):
     """``flash_attention`` under the active mesh.
 
@@ -120,12 +121,13 @@ def sharded_flash_attention(q, k, v, *, causal: bool = True,
     if mesh is None or mesh.size == 1 \
             or resolve_impl(impl) not in ("pallas", "interpret"):
         return flash_attention(q, k, v, causal=causal, window=window,
-                               impl=impl)
+                               scale=scale, impl=impl)
     batch = spec(("batch",), active[1], mesh, q.shape[:1])
     part = jax.sharding.PartitionSpec(*batch, None, None, None)
     return jax.shard_map(
         lambda q, k, v: flash_attention(q, k, v, causal=causal,
-                                        window=window, impl=impl),
+                                        window=window, scale=scale,
+                                        impl=impl),
         mesh=mesh, in_specs=(part, part, part), out_specs=part,
         # pallas_call's out_shape states no varying mesh axes to check
         check_vma=False)(q, k, v)

@@ -5,8 +5,11 @@ where ``axes`` mirrors ``params`` with tuples of *logical* axis names
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.sharding import constrain
 
@@ -82,11 +85,49 @@ def groupnorm_heads(x, scale, bias, eps: float = 64e-5):
 # --------------------------------------------------------------------------
 # RoPE
 # --------------------------------------------------------------------------
-def apply_rope(x, positions, theta: float = 10000.0):
-    """x: (B, H, T, D); positions: (B, T) absolute positions."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor, 0.1 * mscale * ln(factor) + 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_inv_freq(dim: int, theta: float, factor: float = 1.0,
+                  original_max_pos: int = 0, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> np.ndarray:
+    """Inverse frequencies of the ``dim // 2`` rotated pairs.
+
+    With ``factor`` > 1, YaRN's (arXiv:2309.00071, as DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``): pairs that turn fewer than
+    ``beta_slow`` times over ``original_max_pos`` positions are
+    interpolated (frequency / factor), those that turn more than
+    ``beta_fast`` times are kept, with a linear ramp between."""
+    half = dim // 2
+    extra = theta ** (-np.arange(half, dtype=np.float64) / half)
+    if factor <= 1:
+        return extra.astype(np.float32)
+
+    def pair(rotations):   # the pair that turns ``rotations`` times
+        return dim * math.log(original_max_pos / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair(beta_fast)), 0)
+    high = min(math.ceil(pair(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    keep = 1.0 - np.clip((np.arange(half) - low) / (high - low), 0, 1)
+    return (extra / factor * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def apply_rope(x, positions, theta: float = 10000.0, inv_freq=None):
+    """x: (B, H, T, D); positions: (B, T) absolute positions.
+
+    Rotate-half pairs (i, i + D/2) at ``inv_freq`` (plain RoPE at ``theta``
+    when None)."""
     d = x.shape[-1]
     half = d // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freq = jnp.asarray(inv_freq, jnp.float32)
     ang = positions[:, None, :, None].astype(jnp.float32) * freq  # (B,1,T,half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = x[..., :half], x[..., half:]

@@ -1,105 +1,155 @@
-"""Top-k routed Mixture-of-Experts FFN (dbrx-style fine-grained / qwen3-style
-many-expert), expert-parallel over the "model" mesh axis.
+"""Top-k routed Mixture-of-Experts FFN over the experts this chip holds.
 
-Dispatch is the sort-free mesh-tensorflow scheme, vmapped over the batch row
-so every cumsum/scatter is *local to a data shard* (no cross-shard sort):
+The layer is told which experts it holds, ``[offset, offset + held)`` of
+the router's ``num_experts``, and computes their part of the result: the
+expert-parallel share of one chip, run without its exchange (a layer that
+holds every expert is the whole layer).  Dropless:
 
-  1. router top-k -> (T, k) expert ids + renormalized weights,
-  2. position-in-expert via a cumulative count over the T*k assignments,
-     drop beyond per-row capacity C = ceil(k * T / E * capacity_factor),
-  3. scatter tokens into an (E, C, d_model) dispatch buffer,
-  4. per-expert SwiGLU via batched einsum with weights sharded on the
-     expert axis -- GSPMD turns the (data-sharded tokens) -> (expert-sharded
-     buffer) handoff into the canonical MoE all-to-all,
-  5. gather back, weight, and sum the k contributions.
+  1. router (f32) over every expert -> softmax -> greedy top-k ids and
+     weights (renormalised over the k only with ``norm_topk_prob``),
+  2. the (token, slot) pairs whose expert is held, counting-sorted by
+     expert; pairs of other experts sort after them and are never computed,
+  3. one grouped matmul over the sorted rows for gate and up, then one for
+     down (``jax.lax.ragged_dot``: on a TPU one Mosaic kernel each, named
+     ``ragged-dot-*`` in the trace), no capacity and no dropped pair,
+  4. each pair's row weighted and summed back into its token.
 
-Also returns the switch-style load-balancing auxiliary loss.
+Shared experts (a SwiGLU of width ``shared * d_ff``) run on every token and
+are added once.  Also returns the per-sequence balance loss (DeepSeek's
+``seq_aux``, the switch loss per sequence): ``coef * sum_i f_i P_i`` with
+``f_i = E / (k T) * count_i`` and ``P_i`` the mean router probability,
+averaged over sequences; and the rows each held expert computed.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.sharding import constrain
-
-from .layers import _dense_init
+from .layers import _dense_init, ffn_apply, ffn_init
 
 
-def moe_init(key, d_model, num_experts, d_ff):
-    kr, k1, k3 = jax.random.split(key, 3)
+def moe_init(key, d_model, num_experts, d_ff, *, held: int = 0,
+             shared: int = 0):
+    """Router over ``num_experts``; weights of ``held`` experts (0: all)."""
+    kr, k1, k3, ks = jax.random.split(key, 4)
+    held = held or num_experts
     p, a = {}, {}
     p["router"], a["router"] = _dense_init(kr, (d_model, num_experts),
                                            ("embed", None))
-    # gate/up expert weights stacked: one dispatch contraction, one bwd
-    # dx all-reduce (hillclimb H1)
-    p["w_gu"] = jax.random.normal(k1, (2, num_experts, d_model, d_ff),
+    # gate/up expert weights stacked: one grouped matmul for both
+    p["w_gu"] = jax.random.normal(k1, (2, held, d_model, d_ff),
                                   jnp.float32) * d_model ** -0.5
     a["w_gu"] = ("stack", "experts", "embed", "expert_ff")
-    p["w_down"] = jax.random.normal(k3, (num_experts, d_ff, d_model),
+    p["w_down"] = jax.random.normal(k3, (held, d_ff, d_model),
                                     jnp.float32) * d_ff ** -0.5
     a["w_down"] = ("experts", "expert_ff", "embed")
+    if shared:
+        p["shared"], a["shared"] = ffn_init(ks, d_model, shared * d_ff)
     return p, a
 
 
-def _dispatch_row(x, ids, weights, capacity, num_experts):
-    """Per-batch-row dispatch. x: (T, D); ids/weights: (T, k).
+@jax.custom_vjp
+def _to_rows(x, order, dest):
+    """Sorted row j takes the token of pair ``order[j]``; x: (N, D)."""
+    k = order.shape[0] // x.shape[0]
+    return x[order // k]
 
-    Returns (xe: (E, C, D), slot: (T*k,), keep: (T*k,), token_of: (T*k,)).
-    """
-    t, k = ids.shape
-    flat_ids = ids.reshape(t * k)                        # token-major order
-    oh = jax.nn.one_hot(flat_ids, num_experts, dtype=jnp.int32)
-    pos = jnp.cumsum(oh, axis=0) - oh                     # (T*k, E)
-    pos = jnp.sum(pos * oh, axis=1)                       # position in expert
-    keep = pos < capacity
-    slot = jnp.where(keep, flat_ids * capacity + pos, num_experts * capacity)
-    token_of = jnp.arange(t * k) // k
-    d = x.shape[-1]
-    buf = jnp.zeros((num_experts * capacity + 1, d), x.dtype)
-    xe = buf.at[slot].add(x[token_of] * keep[:, None].astype(x.dtype))
-    return xe[:-1].reshape(num_experts, capacity, d), slot, keep, token_of
+
+def _to_rows_fwd(x, order, dest):
+    return _to_rows(x, order, dest), (order, dest, x.shape[0])
+
+
+def _to_rows_bwd(res, g):
+    # each token's k pairs gathered back from their rows: no scatter
+    order, dest, n = res
+    return g[dest].reshape(n, -1, g.shape[-1]).sum(axis=1), None, None
+
+
+_to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
+
+
+@jax.custom_vjp
+def _from_rows(y, dest, order):
+    """Pair i takes sorted row ``dest[i]`` (a permutation, inverse
+    ``order``)."""
+    return y[dest]
+
+
+def _from_rows_fwd(y, dest, order):
+    return y[dest], (dest, order)
+
+
+def _from_rows_bwd(res, g):
+    dest, order = res
+    return g[order], None, None
+
+
+_from_rows.defvjp(_from_rows_fwd, _from_rows_bwd)
+
+
+def _routed(w_gu, w_down, x, top_p, top_ids, *, offset, act):
+    """The held experts' part for N tokens x: (N, D) and their top-k
+    weights and router ids (N, k).  Returns ((N, D), rows per expert)."""
+    n_tok, d = x.shape
+    k = top_ids.shape[-1]
+    held, f, _ = w_down.shape
+    n = n_tok * k
+    with jax.named_scope("moe/dispatch"):
+        local = top_ids.reshape(n) - offset
+        group = jnp.where((local >= 0) & (local < held), local, held)
+        oh = jax.nn.one_hot(group, held + 1, dtype=jnp.int32)   # (n, held+1)
+        counts = jnp.sum(oh, axis=0)
+        rank = jnp.sum((jnp.cumsum(oh, axis=0) - oh) * oh, axis=1)
+        dest = (jnp.cumsum(counts) - counts)[group] + rank      # pair -> row
+        order = jnp.zeros((n,), jnp.int32).at[dest].set(
+            jnp.arange(n, dtype=jnp.int32))                     # row -> pair
+        rows = counts[:held]
+        live = (jnp.arange(n) < jnp.sum(rows))[:, None]
+        xs = jnp.where(live, _to_rows(x, order, dest), 0)
+
+    with jax.named_scope("moe/experts"):
+        # rows past the held experts' are not computed by the grouped
+        # matmul: masked on the way in and out, so nothing they hold
+        # reaches a sum or a gradient
+        w_gu = w_gu.astype(x.dtype).transpose(1, 2, 0, 3)
+        gu = jax.lax.ragged_dot(xs, w_gu.reshape(held, d, 2 * f), rows)
+        h = act(gu[:, :f]) * gu[:, f:]
+        ys = jax.lax.ragged_dot(h, w_down.astype(x.dtype), rows)
+        ys = jnp.where(live, ys, 0)
+
+    with jax.named_scope("moe/combine"):
+        # a contraction over the k slots, accumulated in f32: the (pairs, D)
+        # products are never materialised in f32
+        pairs = _from_rows(ys, dest, order).reshape(n_tok, k, d)
+        y = jnp.einsum("nkd,nk->nd", pairs, top_p,
+                       preferred_element_type=jnp.float32)
+    return y.astype(x.dtype), rows
 
 
 def moe_apply(params, x, *, num_experts, experts_per_token,
-              capacity_factor=1.25, aux_coef=0.01, act=jax.nn.silu):
-    """x: (B, T, d_model) -> (y, aux_loss)."""
+              expert_offset: int = 0, norm_topk_prob: bool = True,
+              aux_coef=0.01, act=jax.nn.silu):
+    """x: (B, T, d_model) -> (y, aux_loss, rows per held expert)."""
     b, t, d = x.shape
-    k = experts_per_token
-    e = num_experts
-    capacity = max(int(k * t / e * capacity_factor), 1)
+    k, e = experts_per_token, num_experts
 
-    logits = (x @ params["router"].astype(x.dtype)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)               # (B, T, E)
-    top_p, top_ids = jax.lax.top_k(probs, k)              # (B, T, k)
-    top_w = (top_p / jnp.sum(top_p, axis=-1, keepdims=True)).astype(x.dtype)
+    with jax.named_scope("moe/router"):
+        logits = jnp.einsum("btd,de->bte", x.astype(jnp.float32),
+                            params["router"])
+        probs = jax.nn.softmax(logits, axis=-1)           # (B, T, E)
+        top_p, top_ids = jax.lax.top_k(probs, k)          # (B, T, k)
+        if norm_topk_prob:
+            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        frac = jnp.mean(jax.nn.one_hot(top_ids, e, dtype=jnp.float32),
+                        axis=(1, 2))                      # (B, E)
+        mean_p = jnp.mean(probs, axis=1)                  # (B, E)
+        aux = aux_coef * e * jnp.mean(jnp.sum(frac * mean_p, axis=-1))
 
-    # load-balancing aux loss (switch): E * mean_e(frac_routed * mean_prob)
-    frac = jnp.mean(jax.nn.one_hot(top_ids, e, dtype=jnp.float32),
-                    axis=(1, 2))                          # (B, E)
-    mean_p = jnp.mean(probs, axis=1)                      # (B, E)
-    aux = aux_coef * e * jnp.mean(jnp.sum(frac * mean_p, axis=-1))
-
-    xe, slot, keep, token_of = jax.vmap(
-        lambda xr, ir, wr: _dispatch_row(xr, ir, wr, capacity, e)
-    )(x, top_ids, top_w)
-    xe = constrain(xe, "batch", "act_experts", None, None)
-
-    gu = jnp.einsum("becd,kedf->kbecf", xe, params["w_gu"].astype(x.dtype))
-    h = act(gu[0]) * gu[1]
-    h = constrain(h, "batch", "act_experts", None, None)
-    ye = jnp.einsum("becf,efd->becd", h, params["w_down"].astype(x.dtype))
-    # combine side (hillclimb H6): gather expert outputs from a *replicated*
-    # buffer -- one all-gather of (E, C, D) -- instead of gathering from the
-    # expert-sharded buffer, whose backward scatter-add forces a full
-    # (T*k, D) all-reduce (~4x the bytes, measured on qwen3)
-    ye = constrain(ye, "batch", None, None, None)
-
-    def _combine_row(ye_r, slot_r, keep_r, token_of_r, w_r):
-        flat = jnp.concatenate(
-            [ye_r.reshape(e * capacity, d), jnp.zeros((1, d), ye_r.dtype)], 0)
-        contrib = flat[slot_r] * (keep_r[:, None] * w_r.reshape(-1)[:, None]
-                                  ).astype(ye_r.dtype)
-        return jnp.zeros((t, d), ye_r.dtype).at[token_of_r].add(contrib)
-
-    y = jax.vmap(_combine_row)(ye, slot, keep, token_of, top_w)
-    return constrain(y, "batch", "seq", "act_embed"), aux
+    y, rows = _routed(params["w_gu"], params["w_down"], x.reshape(b * t, d),
+                      top_p.reshape(b * t, k).astype(x.dtype),
+                      top_ids.reshape(b * t, k), offset=expert_offset,
+                      act=act)
+    y = y.reshape(b, t, d)
+    if "shared" in params:
+        y = y + ffn_apply(params["shared"], x)
+    return y, aux, rows

@@ -19,7 +19,8 @@ def _is_axes(x) -> bool:
 def count_params(cfg: ModelConfig, active_only: bool = False) -> int:
     """Exact parameter count from the abstract init (no allocation).
 
-    ``active_only``: MoE expert weights scaled by k/E (per-token activation).
+    ``active_only``: routed expert weights scaled by k/E (per-token
+    activation); shared experts are active on every token.
     """
     from .transformer import abstract_params
 
@@ -31,7 +32,8 @@ def count_params(cfg: ModelConfig, active_only: bool = False) -> int:
         n = int(np.prod(leaf.shape))
         keys = [getattr(p, "key", getattr(p, "name", "")) for p in path]
         if active_only and cfg.num_experts and any(
-                k in _EXPERT_KEYS for k in keys) and "moe" in keys:
+                k in _EXPERT_KEYS for k in keys) and "moe" in keys \
+                and "shared" not in keys:
             n = int(n * frac)
         total += n
     return int(total)

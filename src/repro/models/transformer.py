@@ -1,6 +1,6 @@
-"""The shared LM backbone: one parameterized definition covering all ten
-assigned architectures (dense / MoE / enc-dec audio / VLM / RWKV-6 SSM /
-RG-LRU hybrid).
+"""The shared LM backbone: one parameterized definition covering every
+architecture of ``ARCH_IDS`` (dense / MoE, with GQA or latent attention /
+enc-dec audio / VLM / RWKV-6 SSM / RG-LRU hybrid).
 
 Structure
 ---------
@@ -20,6 +20,7 @@ Hybrid archs scan over *super-layers* (one pattern period, e.g.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -30,6 +31,7 @@ from repro.configs.base import ModelConfig
 from repro.sharding import constrain
 
 from . import attention as attn
+from . import mla as mla_lib
 from . import moe as moe_lib
 from . import rglru_layer as rglru
 from . import rwkv6_layer as rwkv
@@ -50,22 +52,25 @@ def _is_axes(x) -> bool:
 # ==========================================================================
 def stack_plan(cfg: ModelConfig) -> Dict[str, Any]:
     """How layers are grouped for scan: one scanned *super-layer* holds one
-    pattern period; leftovers become explicit tail layers."""
+    pattern period; leftovers become explicit tail layers, and an MoE
+    model's leading dense layers explicit lead layers before the scan."""
     if cfg.mixer == "rwkv6":
         return dict(scan_kinds=("rwkv",), scan_len=cfg.num_layers,
-                    tail_kinds=(), enc_layers=0)
+                    tail_kinds=(), enc_layers=0, lead_kinds=())
     if cfg.mixer == "rglru_hybrid":
         period = cfg.pattern or ("rec", "rec", "attn")
         n_scan = cfg.num_layers // len(period)
         n_tail = cfg.num_layers - n_scan * len(period)
         tail = (cfg.tail_layers or ("rec",) * n_tail)[:n_tail]
         return dict(scan_kinds=tuple(period), scan_len=n_scan,
-                    tail_kinds=tuple(tail), enc_layers=0)
+                    tail_kinds=tuple(tail), enc_layers=0, lead_kinds=())
     if cfg.is_encdec:
         return dict(scan_kinds=("dec",), scan_len=cfg.num_layers,
-                    tail_kinds=(), enc_layers=cfg.encoder_layers)
-    return dict(scan_kinds=("attn",), scan_len=cfg.num_layers,
-                tail_kinds=(), enc_layers=0)
+                    tail_kinds=(), enc_layers=cfg.encoder_layers,
+                    lead_kinds=())
+    lead = ("dense",) * (cfg.first_dense_layers if cfg.ffn == "moe" else 0)
+    return dict(scan_kinds=("attn",), scan_len=cfg.num_layers - len(lead),
+                tail_kinds=(), enc_layers=0, lead_kinds=lead)
 
 
 def _layer_window(cfg: ModelConfig, kind: str) -> Optional[int]:
@@ -78,22 +83,27 @@ def _layer_window(cfg: ModelConfig, kind: str) -> Optional[int]:
 # per-layer blocks
 # ==========================================================================
 def _block_init(key, cfg: ModelConfig, kind: str):
+    """``dense`` is an attention layer with a dense FFN in an MoE model."""
     ks = jax.random.split(key, 8)
     p, a = {}, {}
     p["norm1"], a["norm1"] = rmsnorm_init(cfg.d_model)
-    if kind in ("attn", "dec"):
-        p["attn"], a["attn"] = attn.attn_init(
-            ks[0], cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
-            cfg.resolved_head_dim, qkv_bias=cfg.qkv_bias)
+    if kind in ("attn", "dec", "dense"):
+        if cfg.mla:
+            p["attn"], a["attn"] = mla_lib.mla_init(ks[0], cfg)
+        else:
+            p["attn"], a["attn"] = attn.attn_init(
+                ks[0], cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
+                cfg.resolved_head_dim, qkv_bias=cfg.qkv_bias)
         if kind == "dec":
             p["norm_x"], a["norm_x"] = rmsnorm_init(cfg.d_model)
             p["xattn"], a["xattn"] = attn.attn_init(
                 ks[1], cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
                 cfg.resolved_head_dim, qkv_bias=cfg.qkv_bias, cross=True)
         p["norm2"], a["norm2"] = rmsnorm_init(cfg.d_model)
-        if cfg.ffn == "moe":
+        if cfg.ffn == "moe" and kind != "dense":
             p["moe"], a["moe"] = moe_lib.moe_init(
-                ks[2], cfg.d_model, cfg.num_experts, cfg.resolved_moe_d_ff)
+                ks[2], cfg.d_model, cfg.num_experts, cfg.resolved_moe_d_ff,
+                held=cfg.resolved_experts_held, shared=cfg.shared_experts)
         else:
             p["ffn"], a["ffn"] = ffn_init(ks[2], cfg.d_model, cfg.d_ff)
     elif kind == "rwkv":
@@ -112,13 +122,17 @@ def _block_init(key, cfg: ModelConfig, kind: str):
 
 
 def _ffn_or_moe(cfg, p, h):
-    if cfg.ffn == "moe":
+    """(y, aux loss, rows per held expert or None)."""
+    if "moe" in p:
         return moe_lib.moe_apply(
             p["moe"], h, num_experts=cfg.num_experts,
             experts_per_token=cfg.experts_per_token,
-            capacity_factor=cfg.capacity_factor,
+            expert_offset=cfg.expert_offset,
+            norm_topk_prob=cfg.norm_topk_prob,
             aux_coef=cfg.router_aux_coef)
-    return ffn_apply(p["ffn"], h, kind=cfg.ffn), jnp.zeros((), jnp.float32)
+    kind = "swiglu" if cfg.ffn == "moe" else cfg.ffn
+    return ffn_apply(p["ffn"], h, kind=kind), jnp.zeros((), jnp.float32), \
+        None
 
 
 def _block_apply(cfg: ModelConfig, p: Params, x, *, kind: str, positions,
@@ -127,25 +141,31 @@ def _block_apply(cfg: ModelConfig, p: Params, x, *, kind: str, positions,
 
     ``state`` is None for pure training; for prefill it is this layer's
     cache slot and the updated cache is returned.
-    Returns (x, aux, new_state)."""
+    Returns (x, aux, rows, new_state): rows per held expert of an MoE
+    layer, else None."""
     window = _layer_window(cfg, kind)
     aux = jnp.zeros((), jnp.float32)
+    rows = None
     new_state = state
     h = rmsnorm(p["norm1"], x)
-    if kind in ("attn", "dec"):
+    if kind in ("attn", "dec", "dense"):
         kw = dict(num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
                   head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
                   impl=impl)
+        if cfg.mla:
+            self_attn = functools.partial(mla_lib.mla_apply, cfg, p["attn"],
+                                          h, positions=positions, impl=impl)
+        else:
+            self_attn = functools.partial(attn.attn_apply, p["attn"], h,
+                                          positions=positions, causal=causal,
+                                          window=window, **kw)
         if state is not None:
-            y, kvc = attn.attn_apply(p["attn"], h, positions=positions,
-                                     causal=causal, window=window,
-                                     return_cache=True, **kw)
+            y, kvc = self_attn(return_cache=True)
             new_state = dict(state,
                              self=_write_prefill_cache(state["self"], kvc,
                                                        window))
         else:
-            y = attn.attn_apply(p["attn"], h, positions=positions,
-                                causal=causal, window=window, **kw)
+            y = self_attn()
         x = x + checkpoint_name(y, "psum_out")
         if kind == "dec":
             hx = rmsnorm(p["norm_x"], x)
@@ -161,7 +181,7 @@ def _block_apply(cfg: ModelConfig, p: Params, x, *, kind: str, positions,
                     cross = attn.KVCache(k=kq, v=vq, ks=ksc, vs=vsc)
                 new_state = dict(new_state, cross=cross)
         h2 = rmsnorm(p["norm2"], x)
-        y, aux = _ffn_or_moe(cfg, p, h2)
+        y, aux, rows = _ffn_or_moe(cfg, p, h2)
         x = x + checkpoint_name(y, "psum_out")
     elif kind == "rwkv":
         st = state if state is not None else rwkv.init_state(
@@ -187,7 +207,7 @@ def _block_apply(cfg: ModelConfig, p: Params, x, *, kind: str, positions,
                                 "psum_out")
         if state is None:
             new_state = None
-    return constrain(x, "batch", "seq", "act_embed"), aux, new_state
+    return constrain(x, "batch", "seq", "act_embed"), aux, rows, new_state
 
 
 def _write_prefill_cache(cache: attn.KVCache, kvc: attn.KVCache, window):
@@ -227,11 +247,15 @@ def _block_decode(cfg: ModelConfig, p: Params, x, idx, *, kind: str,
     """One-token decode. x: (B, 1, D). Returns (x, new_state)."""
     window = _layer_window(cfg, kind)
     h = rmsnorm(p["norm1"], x)
-    if kind in ("attn", "dec"):
+    if kind in ("attn", "dec", "dense"):
         kw = dict(num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
                   head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta)
-        y, kvc = attn.attn_decode(p["attn"], h, state["self"], idx,
-                                  window=window, **kw)
+        if cfg.mla:
+            y, kvc = mla_lib.mla_decode(cfg, p["attn"], h, state["self"],
+                                        idx)
+        else:
+            y, kvc = attn.attn_decode(p["attn"], h, state["self"], idx,
+                                      window=window, **kw)
         state = dict(state, self=kvc)
         x = x + y
         if kind == "dec":
@@ -240,7 +264,7 @@ def _block_decode(cfg: ModelConfig, p: Params, x, idx, *, kind: str,
                                     cross=True, use_rope=False, **kw)
             x = x + y
         h2 = rmsnorm(p["norm2"], x)
-        y, _ = _ffn_or_moe(cfg, p, h2)
+        y, _, _ = _ffn_or_moe(cfg, p, h2)
         x = x + y
     elif kind == "rwkv":
         y, shift_tm, wkv_new = rwkv.timemix_apply(
@@ -289,7 +313,8 @@ def _stacked_init(key, cfg, kinds, n):
 
 def init_params(cfg: ModelConfig, key) -> Tuple[Params, Params]:
     plan = stack_plan(cfg)
-    ks = jax.random.split(key, 6 + len(plan["tail_kinds"]))
+    n_tail = len(plan["tail_kinds"])
+    ks = jax.random.split(key, 6 + n_tail + len(plan["lead_kinds"]))
     p, a = {}, {}
     p["embed"], a["embed"] = embed_init(ks[0], cfg.padded_vocab, cfg.d_model)
     if cfg.frontend in ("frames", "patches"):
@@ -303,6 +328,9 @@ def init_params(cfg: ModelConfig, key) -> Tuple[Params, Params]:
                                            plan["scan_len"])
     for i, kind in enumerate(plan["tail_kinds"]):
         p[f"tail{i}"], a[f"tail{i}"] = _block_init(ks[6 + i], cfg, kind)
+    for i, kind in enumerate(plan["lead_kinds"]):
+        p[f"lead{i}"], a[f"lead{i}"] = _block_init(ks[6 + n_tail + i], cfg,
+                                                   kind)
     p["final_norm"], a["final_norm"] = rmsnorm_init(cfg.d_model)
     p["lm_head"], a["lm_head"] = lm_head_init(ks[5], cfg.d_model,
                                               cfg.padded_vocab)
@@ -362,8 +390,9 @@ def _run_encoder(cfg, params, batch, impl):
     epos = jnp.broadcast_to(jnp.arange(s), (b, s))
 
     def ebody(e, lp):
-        e, _, _ = _block_apply(cfg, lp["b0"], e, kind="attn", positions=epos,
-                               state=None, impl=impl, causal=False)
+        e, _, _, _ = _block_apply(cfg, lp["b0"], e, kind="attn",
+                                  positions=epos, state=None, impl=impl,
+                                  causal=False)
         return e, None
 
     e, _ = jax.lax.scan(_remat(ebody, cfg.remat_policy), enc_in,
@@ -373,62 +402,81 @@ def _run_encoder(cfg, params, batch, impl):
 
 def _run_stack(cfg, params, x, positions, *, plan, impl, enc_out=None,
                caches=None):
-    """Scan the super-layer stack (+ tail layers).
+    """Lead layers, the scanned super-layer stack, tail layers.
 
-    ``caches`` is None (training) or {"stack": stacked-cache, "tails": [...]}
-    (prefill).  Returns (x, aux, new_caches)."""
+    ``caches`` is None (training) or {"lead": [...], "stack": stacked-cache,
+    "tails": [...]} (prefill).  Returns (x, aux, rows, new_caches): rows is
+    (scanned layers, held experts) for an MoE stack, else None."""
     kinds = plan["scan_kinds"]
+    aux = jnp.zeros((), jnp.float32)
+    new_lead = []
+    for i, kind in enumerate(plan["lead_kinds"]):
+        st = None if caches is None else caches["lead"][i]
+        block = _remat(functools.partial(_block_apply, cfg, kind=kind,
+                                         positions=positions, impl=impl),
+                       cfg.remat_policy)
+        x, aux_i, _, st = block(params[f"lead{i}"], x, state=st)
+        aux = aux + aux_i
+        new_lead.append(st)
 
     def body(carry, xs):
         x, aux = carry
         lp, cache_in = xs
         new_cache = cache_in
+        rows = None
         for i, kind in enumerate(kinds):
             st = None if cache_in is None else cache_in[f"b{i}"]
-            x, aux_i, st = _block_apply(cfg, lp[f"b{i}"], x, kind=kind,
-                                        positions=positions, state=st,
-                                        enc_out=enc_out, impl=impl)
+            x, aux_i, rows_i, st = _block_apply(
+                cfg, lp[f"b{i}"], x, kind=kind, positions=positions,
+                state=st, enc_out=enc_out, impl=impl)
             aux = aux + aux_i
+            rows = rows_i if rows is None else rows
             if cache_in is not None:
                 new_cache = dict(new_cache, **{f"b{i}": st})
-        return (x, aux), new_cache
+        return (x, aux), (new_cache, rows)
 
-    (x, aux), new_stack = jax.lax.scan(
-        _remat(body, cfg.remat_policy),
-        (x, jnp.zeros((), jnp.float32)),
+    (x, aux), (new_stack, rows) = jax.lax.scan(
+        _remat(body, cfg.remat_policy), (x, aux),
         (params["stack"], caches["stack"] if caches else None))
     new_tails = []
     for i, kind in enumerate(plan["tail_kinds"]):
         st = None if caches is None else caches["tails"][i]
-        x, aux_i, st = _block_apply(cfg, params[f"tail{i}"], x, kind=kind,
-                                    positions=positions, state=st,
-                                    enc_out=enc_out, impl=impl)
+        x, aux_i, _, st = _block_apply(cfg, params[f"tail{i}"], x, kind=kind,
+                                       positions=positions, state=st,
+                                       enc_out=enc_out, impl=impl)
         aux = aux + aux_i
         new_tails.append(st)
-    new_caches = None if caches is None else dict(caches, stack=new_stack,
-                                                  tails=new_tails)
-    return x, aux, new_caches
+    new_caches = None if caches is None else dict(
+        caches, lead=new_lead, stack=new_stack, tails=new_tails)
+    return x, aux, rows, new_caches
 
 
-def forward(cfg: ModelConfig, params, batch, *, impl: Optional[str] = None):
-    """Training / scoring forward pass. Returns (logits, aux_loss)."""
+def _forward(cfg: ModelConfig, params, batch, impl):
     plan = stack_plan(cfg)
     x, positions, prefix = _embed_inputs(cfg, params, batch)
     enc_out = _run_encoder(cfg, params, batch, impl) if plan["enc_layers"] \
         else None
-    x, aux, _ = _run_stack(cfg, params, x, positions, plan=plan, impl=impl,
-                           enc_out=enc_out)
+    x, aux, rows, _ = _run_stack(cfg, params, x, positions, plan=plan,
+                                 impl=impl, enc_out=enc_out)
     x = rmsnorm(params["final_norm"], x)
     if prefix:
         x = x[:, prefix:, :]
     logits = lm_head_apply(params["lm_head"], x,
                            valid_vocab=cfg.vocab_size)
+    return logits, aux, rows
+
+
+def forward(cfg: ModelConfig, params, batch, *, impl: Optional[str] = None):
+    """Training / scoring forward pass. Returns (logits, aux_loss)."""
+    logits, aux, _ = _forward(cfg, params, batch, impl)
     return logits, aux
 
 
 def loss_fn(cfg: ModelConfig, params, batch, *, impl: Optional[str] = None):
-    """Next-token cross-entropy (+ MoE aux). Returns (loss, metrics)."""
-    logits, aux = forward(cfg, params, batch, impl=impl)
+    """Next-token cross-entropy (+ MoE aux). Returns (loss, metrics); an
+    MoE model's metrics carry ``moe_rows``, the rows each held expert
+    computed in each scanned layer (a device array)."""
+    logits, aux, rows = _forward(cfg, params, batch, impl)
     labels = batch["labels"]
     logits = logits[:, :-1, :].astype(jnp.float32)
     targets = labels[:, 1:]
@@ -438,7 +486,10 @@ def loss_fn(cfg: ModelConfig, params, batch, *, impl: Optional[str] = None):
     mask = (targets >= 0).astype(jnp.float32)
     xent = jnp.sum((logz - gold) * mask) / jnp.maximum(jnp.sum(mask), 1.0)
     loss = xent + aux
-    return loss, {"xent": xent, "aux": aux}
+    metrics = {"xent": xent, "aux": aux}
+    if rows is not None:
+        metrics["moe_rows"] = rows
+    return loss, metrics
 
 
 # ==========================================================================
@@ -447,7 +498,9 @@ def loss_fn(cfg: ModelConfig, params, batch, *, impl: Optional[str] = None):
 def _kind_cache_init(cfg: ModelConfig, kind: str, batch: int, max_len: int,
                      dtype):
     window = _layer_window(cfg, kind)
-    if kind == "attn":
+    if kind in ("attn", "dense") and cfg.mla:
+        return {"self": mla_lib.init_cache(cfg, batch, max_len, dtype)}
+    if kind in ("attn", "dense"):
         s = min(max_len, window) if window else max_len
         return {"self": attn.init_kv_cache(batch, cfg.num_kv_heads, s,
                                            cfg.resolved_head_dim, dtype,
@@ -469,7 +522,7 @@ def _kind_cache_init(cfg: ModelConfig, kind: str, batch: int, max_len: int,
 
 
 def _kind_cache_axes(kind: str, quant: bool = False):
-    if kind == "attn":
+    if kind in ("attn", "dense"):
         return {"self": attn.cache_axes(quant)}
     if kind == "dec":
         return {"self": attn.cache_axes(quant),
@@ -493,7 +546,9 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
         lambda leaf: jnp.zeros((n,) + leaf.shape, leaf.dtype), single)
     tails = [_kind_cache_init(cfg, kind, batch, max_len, dtype)
              for kind in plan["tail_kinds"]]
-    return {"stack": stacked, "tails": tails,
+    lead = [_kind_cache_init(cfg, kind, batch, max_len, dtype)
+            for kind in plan["lead_kinds"]]
+    return {"lead": lead, "stack": stacked, "tails": tails,
             "idx": jnp.zeros((), jnp.int32)}
 
 
@@ -505,8 +560,10 @@ def cache_axes(cfg: ModelConfig) -> Dict[str, Any]:
     stacked = jax.tree.map(lambda ax: ("layers",) + tuple(ax), single,
                            is_leaf=_is_axes)
     tails = [_kind_cache_axes(kind, cfg.kv_quant)
-         for kind in plan["tail_kinds"]]
-    return {"stack": stacked, "tails": tails, "idx": ()}
+             for kind in plan["tail_kinds"]]
+    lead = [_kind_cache_axes(kind, cfg.kv_quant)
+            for kind in plan["lead_kinds"]]
+    return {"lead": lead, "stack": stacked, "tails": tails, "idx": ()}
 
 
 def prefill(cfg: ModelConfig, params, batch, cache, *,
@@ -518,8 +575,8 @@ def prefill(cfg: ModelConfig, params, batch, cache, *,
     x, positions, prefix = _embed_inputs(cfg, params, batch)
     enc_out = _run_encoder(cfg, params, batch, impl) if plan["enc_layers"] \
         else None
-    x, _, caches = _run_stack(cfg, params, x, positions, plan=plan,
-                              impl=impl, enc_out=enc_out, caches=cache)
+    x, _, _, caches = _run_stack(cfg, params, x, positions, plan=plan,
+                                 impl=impl, enc_out=enc_out, caches=cache)
     x = rmsnorm(params["final_norm"], x)
     logits = lm_head_apply(params["lm_head"], x[:, -1:, :],
                            valid_vocab=cfg.vocab_size)[:, 0, :]
@@ -534,6 +591,11 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, *,
     kinds = plan["scan_kinds"]
     x = embed_apply(params["embed"], tokens).astype(cfg.dtype)
     idx = cache["idx"]
+    new_lead = []
+    for i, kind in enumerate(plan["lead_kinds"]):
+        x, st = _block_decode(cfg, params[f"lead{i}"], x, idx, kind=kind,
+                              state=cache["lead"][i], impl=impl)
+        new_lead.append(st)
 
     def body(x, xs):
         lp, lc = xs
@@ -553,5 +615,6 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, *,
     x = rmsnorm(params["final_norm"], x)
     logits = lm_head_apply(params["lm_head"], x,
                            valid_vocab=cfg.vocab_size)[:, 0, :]
-    new_cache = dict(cache, stack=new_stack, tails=new_tails, idx=idx + 1)
+    new_cache = dict(cache, lead=new_lead, stack=new_stack, tails=new_tails,
+                     idx=idx + 1)
     return logits, new_cache

@@ -169,6 +169,7 @@ _COMMON = (
     ("ff", "model"),
     ("experts", "model"),
     ("expert_ff", None),
+    ("kv_lora", None),                 # latent attention's KV rank
     ("vocab", "model"),
     ("rnn", "model"),
     ("conv", None),

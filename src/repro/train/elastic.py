@@ -355,8 +355,11 @@ class ElasticTrainer:
                          for k, v in batch.items()}
                 self.state, metrics = self._step(self.state, batch)
                 step = int(self.state.step)
-                self.metrics_log.append(
-                    {"step": step, "loss": float(metrics["loss"])})
+                entry = {"step": step, "loss": float(metrics["loss"])}
+                if "moe_rows" in metrics:
+                    # kept on the device: read by moe_rows(), not here
+                    entry["moe_rows"] = metrics["moe_rows"]
+                self.metrics_log.append(entry)
             if self._adapt_handles is not None:
                 # work retained inside the adapt window — the whole point of
                 # overlapping: a stop-the-world resize gets zero of these
@@ -373,6 +376,21 @@ class ElasticTrainer:
                 "steps_during_resize": self.steps_during_resize,
                 "interval_changes": self.interval_changes,
                 "ckpt_interval_s": self.client.ckpt_interval_s}
+
+    def moe_rows(self, last: int) -> Optional[np.ndarray]:
+        """Rows each held expert computed in each MoE layer over the last
+        ``last`` steps, (steps, layers, experts), read from the device in
+        one call and recorded as the counter ``moe_rows`` on the trainer's
+        trace; None for a model without MoE layers."""
+        kept = [e["moe_rows"] for e in self.metrics_log[-last:]
+                if "moe_rows" in e] if last > 0 else []
+        if not kept:
+            return None
+        rows = np.stack(jax.device_get(kept)).astype(np.int64)
+        self.tracer.record("moe_rows", self.trace_id, self._track,
+                           steps=len(kept),
+                           rows=rows.sum(axis=0).tolist())
+        return rows
 
     def finalize(self):
         if self._adapt_handles is not None:

@@ -123,3 +123,39 @@ def test_flash_attention_grad_compiles_on_a_mesh(topo):
 
     _assert_kernel(jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
                    .lower(q, kv, kv))
+
+
+# deepseek-v2-lite latent attention at train_4k: 16 heads, q and k 192 wide
+# (128 nope + 64 rope), v 128 wide, batch 4
+MLA_QK, MLA_V = (4, 16, 4096, 192), (4, 16, 4096, 128)
+
+
+def test_flash_attention_mla_forward_compiles(one_chip):
+    qk = _spec(MLA_QK, jnp.bfloat16, one_chip)
+    v = _spec(MLA_V, jnp.bfloat16, one_chip)
+    _assert_kernel(jax.jit(lambda q, k, v: attention(q, k, v, impl="pallas"))
+                   .lower(qk, qk, v))
+
+
+def test_expert_grouped_matmul_compiles(one_chip):
+    """The MoE layer's grouped matmuls at deepseek-v2-lite's widths (8 held
+    experts, 2048 -> 2 x 1408 -> 2048) over a batch of 4 x 4096 tokens' 6
+    slots, forward and backward: each a Mosaic ragged-dot kernel."""
+    rows, d, f, e = 4 * 4096 * 6, 2048, 1408, 8
+    x = _spec((rows, d), jnp.bfloat16, one_chip)
+    w_gu = _spec((e, d, 2 * f), jnp.bfloat16, one_chip)
+    w_down = _spec((e, f, d), jnp.bfloat16, one_chip)
+    sizes = _spec((e,), jnp.int32, one_chip)
+
+    def loss(x, w_gu, w_down, sizes):
+        gu = jax.lax.ragged_dot(x, w_gu, sizes)
+        h = jax.nn.silu(gu[:, :f]) * gu[:, f:]
+        y = jax.lax.ragged_dot(h, w_down, sizes)
+        return jnp.square(y.astype(jnp.float32)).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, w_gu, w_down, sizes).compile().as_text()
+    kernels = [ln for ln in text.splitlines()
+               if "tpu_custom_call" in ln and "ragged-dot" in ln]
+    # gate/up and down forward, and each one's two backward products
+    assert sum("ragged-dot-metadata" not in ln for ln in kernels) >= 6

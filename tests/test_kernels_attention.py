@@ -77,3 +77,57 @@ def test_fully_masked_rows_are_zero():
     ref = attention_ref(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
                         causal=True, window=1)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+
+
+# latent attention (MLA) attends with 192-wide queries and keys over
+# 128-wide values; the same shapes at a small size, and an unaligned pair
+DV_CASES = [
+    # b, hq, hkv, t, s, d_qk, d_v, causal
+    (1, 4, 4, 128, 128, 48, 32, True),
+    (2, 2, 1, 100, 100, 24, 40, True),        # GQA, unaligned T
+    (1, 2, 2, 64, 64, 64, 16, False),
+]
+
+
+@pytest.mark.parametrize("case", DV_CASES)
+def test_value_dim_differs_forward(case):
+    """The Pallas kernel (interpret mode) and the XLA path agree with each
+    other and with the oracle when the value head is narrower or wider
+    than the query/key head (f32, so 3e-5 is a few ulps of the softmax
+    sums)."""
+    b, hq, hkv, t, s, d, dv, causal = case
+    q, k, _ = _mk(b, hq, hkv, t, s, d)
+    v = RNG.standard_normal((b, hkv, s, dv)).astype(np.float32)
+    ref = attention_ref(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+                        causal=causal)
+    got = {impl: attention(q, k, v, causal=causal, impl=impl)
+           for impl in ("interpret", "xla")}
+    assert got["interpret"].shape == (b, hq, t, dv)
+    np.testing.assert_allclose(np.asarray(got["interpret"]),
+                               np.asarray(got["xla"]), atol=3e-5)
+    np.testing.assert_allclose(np.asarray(got["xla"]), np.asarray(ref),
+                               atol=3e-5)
+
+
+@pytest.mark.parametrize("case", DV_CASES)
+def test_value_dim_differs_grads(case):
+    """Gradients through the kernel's forward (interpret mode) and the XLA
+    backward, against the XLA forward's and the oracle's (5e-4 as for equal
+    dims: the backward recomputes the softmax from the saved log-sum-exp)."""
+    b, hq, hkv, t, s, d, dv, causal = case
+    q, k, _ = _mk(b, hq, hkv, t, s, d)
+    v = RNG.standard_normal((b, hkv, s, dv)).astype(np.float32)
+
+    def grads(fn):
+        return jax.grad(lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v))),
+                        argnums=(0, 1, 2))(jnp.asarray(q), jnp.asarray(k),
+                                           jnp.asarray(v))
+
+    g_ref = grads(lambda q, k, v: attention_ref(q, k, v, causal=causal))
+    for impl in ("interpret", "xla"):
+        g = grads(lambda q, k, v: attention(q, k, v, causal=causal,
+                                            impl=impl))
+        for gi, gr, name in zip(g, g_ref, "qkv"):
+            assert gi.shape == gr.shape
+            np.testing.assert_allclose(np.asarray(gi), np.asarray(gr),
+                                       atol=5e-4, err_msg=f"{impl} d{name}")
