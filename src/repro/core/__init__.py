@@ -22,7 +22,8 @@ from .rm import ResizeEvent, ResourceManager
 from .services import (IntervalController, StorageLifecycleService,
                        TelemetryService, daly_interval, young_interval)
 from .simnet import EWMA, FaultInjector, SimClock, SimNIC
-from .snapshot import HostSnapshot, restore_pytree, snapshot_pytree
+from .snapshot import (HostSnapshot, region_metas, restore_pytree,
+                       snapshot_pytree)
 from .tiers import (DeltaState, EncodedRegion, LocalDiskTier, MemoryTier,
                     PFSTier, RemoteObjectTier, StorageTier, TierPipeline,
                     crc32, decode_payload, encode_delta_region,
@@ -44,7 +45,8 @@ __all__ = [
     "get_policy", "ResizeEvent", "ResourceManager",
     "IntervalController", "StorageLifecycleService", "TelemetryService",
     "daly_interval", "young_interval", "EWMA", "FaultInjector",
-    "SimClock", "SimNIC", "HostSnapshot", "restore_pytree", "snapshot_pytree",
+    "SimClock", "SimNIC", "HostSnapshot", "region_metas", "restore_pytree",
+    "snapshot_pytree",
     "MemoryStore", "PFSStore", "MemoryTier", "PFSTier", "LocalDiskTier",
     "RemoteObjectTier", "StorageTier", "TierPipeline", "crc32", "encode_payload",
     "decode_payload", "resolve_codec", "DeltaState", "EncodedRegion",

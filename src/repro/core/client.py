@@ -3,7 +3,8 @@
 Maps 1:1 to the paper's API:
 
     icheck_init            -> ICheckClient.init
-    icheck_add_adapt       -> ICheckClient.add_adapt / add_adapt_snapshot
+    icheck_add_adapt       -> ICheckClient.add_adapt / add_adapt_metas /
+                              add_adapt_snapshot
     icheck_commit          -> ICheckClient.commit            (non-blocking)
     icheck_restart         -> ICheckClient.restart
     icheck_redistribute    -> ICheckClient.redistribute
@@ -447,13 +448,19 @@ class ICheckClient:
         self.controller.register_region(self.app_id, meta)
         return meta
 
-    def add_adapt_snapshot(self, snap) -> None:
-        """Register every region of a ``HostSnapshot`` (JAX pytree path)."""
-        for name, sr in snap.regions.items():
-            meta = sr.meta
+    def add_adapt_metas(self, metas: Dict[str, RegionMeta]) -> None:
+        """Register regions whose metas are already built (JAX pytree
+        path: :func:`repro.core.snapshot.region_metas` reads them from a
+        pytree's shapes and shardings with no D2H copy)."""
+        for name, meta in metas.items():
             meta.codec = self.codec
             self.regions[name] = meta
             self.controller.register_region(self.app_id, meta)
+
+    def add_adapt_snapshot(self, snap) -> None:
+        """Register every region of a ``HostSnapshot``."""
+        self.add_adapt_metas({name: sr.meta
+                              for name, sr in snap.regions.items()})
 
     # ---------------------------------------------------------------- commit
     def commit(self, step: int,

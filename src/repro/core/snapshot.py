@@ -127,6 +127,33 @@ def _device_parts(leaf) -> Tuple[Tuple[planlib.Box, ...], Dict[int, Any],
     return boxes, parts, desc
 
 
+def _raw_meta(name: str, leaf, boxes: Tuple[planlib.Box, ...],
+              parts: Dict[int, Any], desc: PartitionDesc) -> RegionMeta:
+    """The raw region of one leaf, read from its shape, dtype and distinct
+    parts' boxes: no data moves."""
+    dtype = getattr(leaf, "dtype", None)
+    dtype = np.dtype(dtype) if dtype is not None else np.asarray(leaf).dtype
+    volume = sum(int(np.prod([hi - lo for lo, hi in boxes[p]]))
+                 for p in parts)
+    return RegionMeta(name=name, shape=tuple(np.shape(leaf)),
+                      dtype=str(dtype), partition=desc,
+                      nbytes=volume * dtype.itemsize)
+
+
+def region_metas(tree) -> Dict[str, RegionMeta]:
+    """Every leaf's raw region, as a raw ``snapshot_pytree`` of ``tree``
+    gives it, read from shapes, dtypes and shardings: nothing is copied to
+    the host (what registering a state's regions needs)."""
+    import jax
+
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    metas: Dict[str, RegionMeta] = {}
+    for path, leaf in flat:
+        name = _leaf_name(path)
+        metas[name] = _raw_meta(name, leaf, *_device_parts(leaf))
+    return metas
+
+
 def _chain_states(chain_lookup, name: str, num_parts: int,
                   part_sizes: Dict[int, int]):
     """Previous-codes state usable for a device-side delta encode of this
@@ -237,12 +264,8 @@ def _snapshot_leaves(flat, codec: str, chain_lookup, impl: Optional[str],
         boxes, dev_parts, desc = _device_parts(leaf)
         with tracer.span("snapshot/d2h_wait", trace_id, TRACK, region=name):
             parts = {p: np.asarray(a) for p, a in dev_parts.items()}
-            nbytes = sum(p.nbytes for p in parts.values())
-            tracer.note(bytes=nbytes)
-        np_dtype = parts[0].dtype if parts else np.dtype("float32")
-        meta = RegionMeta(name=name, shape=tuple(np.shape(leaf)),
-                          dtype=str(np_dtype),
-                          partition=desc, nbytes=nbytes)
+            tracer.note(bytes=sum(p.nbytes for p in parts.values()))
+        meta = _raw_meta(name, leaf, boxes, dev_parts, desc)
         regions[name] = SnapshotRegion(meta=meta, parts=parts, boxes=boxes)
     return regions
 

@@ -4,8 +4,10 @@ Control flow is exactly the paper's malleable-app skeleton:
 
     MPI_Init_adapt            -> MalleableApp.init_adapt
     icheck_init               -> ICheckClient.init
-    icheck_add_adapt          -> add_adapt_snapshot (every TrainState leaf +
-                                 data-iterator state become iCheck regions)
+    icheck_add_adapt          -> add_adapt_metas (every TrainState leaf,
+                                 its region read from shape and sharding
+                                 with no D2H, + data-iterator state become
+                                 iCheck regions)
     icheck_restart            -> restart()  (fresh start if no checkpoint)
     loop:
         MPI_Probe_adapt       -> probe_adapt
@@ -35,7 +37,7 @@ from repro.core import (ICheckClient, ICheckCluster, MalleableApp,
                         snapshot_pytree)
 from repro.core import events as icheck_events
 from repro.core import plan as planlib
-from repro.core.snapshot import leaf_names, restore_pytree
+from repro.core.snapshot import leaf_names, region_metas, restore_pytree
 from repro.data import SyntheticLMData
 from repro.obs import trace_id_for
 from repro.optim import AdamWConfig, warmup_cosine
@@ -186,10 +188,12 @@ class ElasticTrainer:
         self._step = jax.jit(run, donate_argnums=0)
 
     def _register_regions(self):
-        snap = snapshot_pytree(self.state, step=int(self.state.step),
-                               tracer=self.tracer)
-        self.tracer.note(bytes=snap.total_bytes())
-        self.client.add_adapt_snapshot(snap)
+        # read from shapes and shardings: no byte of the state crosses to
+        # the host (on a resume the restore overwrites it next)
+        metas = region_metas(self.state)
+        self.tracer.note(bytes=sum(m.nbytes for m in metas.values()),
+                         regions=len(metas), host_bytes=0)
+        self.client.add_adapt_metas(metas)
         self.client.add_adapt(DATA_REGION, (2,), "int64",
                               num_parts=1)
 

@@ -637,12 +637,14 @@ def test_traced_resume_gives_trainer_init_and_restore_spans():
     mine = [s for s in spans if s.trace_id == t2.trace_id]
     names = {s.name for s in mine}
     assert {"trainer_init", "trainer_init/state", "trainer_init/register",
-            "restart", "snapshot"} <= names, names
+            "restart"} <= names, names
+    # registration reads shapes and shardings: no snapshot, no D2H
+    assert "snapshot" not in names, names
     (init,) = [s for s in mine if s.name == "trainer_init"]
     (restart,) = [s for s in mine if s.name == "restart"]
     assert init.t0 + init.dur_s <= restart.t0
     reg = [s for s in mine if s.name == "trainer_init/register"][0]
-    assert reg.args["bytes"] > 0
+    assert reg.args["bytes"] > 0 and reg.args["host_bytes"] == 0
     ckpt = [s for s in spans if s.trace_id == trace_id_for("app", 0)]
     got = {s.name for s in ckpt}
     assert {"restore", "restore/fetch", "restore/decode", "restore/place",
