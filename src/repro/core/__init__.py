@@ -28,7 +28,6 @@ from .tiers import (DeltaState, EncodedRegion, LocalDiskTier, MemoryTier,
                     PFSTier, RemoteObjectTier, StorageTier, TierPipeline,
                     crc32, decode_payload, encode_delta_region,
                     encode_payload, q8_chain_decode, resolve_codec)
-from .store import MemoryStore, PFSStore
 from .types import (AppRecord, AppStatus, CheckpointMeta, CkptStatus,
                     ICheckError, IntegrityError, CapacityError, NodeSpec,
                     PartitionDesc, PartitionScheme, RegionMeta, RestoreError,
@@ -46,8 +45,7 @@ __all__ = [
     "IntervalController", "StorageLifecycleService", "TelemetryService",
     "daly_interval", "young_interval", "EWMA", "FaultInjector",
     "SimClock", "SimNIC", "HostSnapshot", "region_metas", "restore_pytree",
-    "snapshot_pytree",
-    "MemoryStore", "PFSStore", "MemoryTier", "PFSTier", "LocalDiskTier",
+    "snapshot_pytree", "MemoryTier", "PFSTier", "LocalDiskTier",
     "RemoteObjectTier", "StorageTier", "TierPipeline", "crc32", "encode_payload",
     "decode_payload", "resolve_codec", "DeltaState", "EncodedRegion",
     "encode_delta_region", "q8_chain_decode", "AppRecord", "AppStatus",

@@ -708,10 +708,11 @@ class ICheckClient:
     def commit_snapshot(self, snap, extra_parts: Optional[Dict] = None,
                         userdata: bytes = b"", blocking: bool = False,
                         drain: bool = True) -> CommitHandle:
-        """Commit a :class:`~repro.core.snapshot.HostSnapshot` whose regions
-        were encoded *on device* (``snapshot_pytree(codec=...)``): the
-        client→agent fabric and every storage tier move the int8 frames the
-        D2H copy already produced.  ``extra_parts`` adds plain host-side
+        """Commit a :class:`~repro.core.snapshot.HostSnapshot`.  Regions
+        encoded *on device* (``snapshot_pytree(codec="q8"|"q8-delta")``)
+        ship as-is: the client→agent fabric and every storage tier move the
+        int8 frames the D2H copy already produced.  Raw regions go through
+        :meth:`commit` and its codec.  ``extra_parts`` adds plain host-side
         regions (e.g. a data-iterator cursor)."""
         self.add_adapt_snapshot(snap)
         encoded = {name: sr.encoded for name, sr in snap.regions.items()
@@ -724,8 +725,8 @@ class ICheckClient:
                            snapshot_trace=snap.trace)
 
     def delta_chain_lookup(self, name: str, num_parts: int):
-        """Previous-codes state for a device-side delta encode (or None when
-        the next frame of ``name`` must be a keyframe)."""
+        """Previous-codes state a delta save counts changed blocks against
+        (or None when the next frame of ``name`` must be a keyframe)."""
         return self.controller.delta_chain(self.app_id, name, num_parts)
 
     def _completer_loop(self) -> None:

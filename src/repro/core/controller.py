@@ -316,9 +316,9 @@ class Controller:
             app = self._apps.get(app_id)
             if app:
                 app.status = AppStatus.FINISHED
-        # release the app's delta-chain state (host codes + device-resident
-        # codes_dev arrays) — long-lived controllers see many apps come and
-        # go, and a finished app will keyframe anyway if it reconnects
+        # release the app's delta-chain state (previous host codes) —
+        # long-lived controllers see many apps come and go, and a finished
+        # app will keyframe anyway if it reconnects
         self.catalog.reset_delta_chains(app_id=app_id, reason="app_finished")
         # likewise the pre-staged resize plans/transfer programs
         self.resize.invalidate(app_id)

@@ -8,14 +8,13 @@ the TPU DMA analogue of the paper's RDMA source buffers) and only then
 gathered, so device compute can proceed underneath.
 
 With ``codec="q8"`` / ``codec="q8-delta"`` the encode runs **on device**
-before the D2H copy: each float region part goes through
-``kernels/ckpt_codec.quantize`` (or ``quantize_delta`` against the
-catalog's previous-codes state from ``chain_lookup``), so the host pulls
-the new int8 codes + 1/256 overhead of f32 scales — ~4x fewer D2H bytes
-than the raw f32 leaves.  For a delta the host then counts the blocks that
-changed against the previous codes, decides the frame from that count
-(sparse deltas only when they are smaller than keyframes) and builds only
-the frame that ships; the resulting
+before the D2H copy: each float region part goes through one kernel,
+``kernels/ckpt_codec.quantize``, so the host pulls the new int8 codes +
+1/256 overhead of f32 scales — ~4x fewer D2H bytes than the raw f32
+leaves.  For ``q8-delta`` the host then counts the blocks that changed
+against the catalog's previous codes (``chain_lookup``), decides the frame
+from that count (sparse deltas only when they are smaller than keyframes)
+and builds only the frame that ships; the resulting
 :class:`~repro.core.tiers.EncodedRegion` frames travel the client→agent
 fabric and the storage tiers as-is (``ICheckClient.commit_snapshot``).
 
@@ -24,8 +23,8 @@ snapshot is a ``snapshot`` span with one child per phase and region —
 ``snapshot/encode`` (kernel launch and async copy), ``snapshot/d2h_wait``,
 ``snapshot/changed`` (the changed-block count of a delta region),
 ``snapshot/xor`` (the rows of a delta that ships), ``snapshot/frame`` —
-and a restore's placement is ``restore/assemble`` and ``restore/h2d`` per
-leaf.
+and a restore's placement (:func:`place_leaf`) is ``restore/assemble`` and
+``restore/h2d`` per leaf.
 """
 from __future__ import annotations
 
@@ -156,8 +155,9 @@ def region_metas(tree) -> Dict[str, RegionMeta]:
 
 def _chain_states(chain_lookup, name: str, num_parts: int,
                   part_sizes: Dict[int, int]):
-    """Previous-codes state usable for a device-side delta encode of this
-    region, or (None, None) when the next frame must be a keyframe."""
+    """The catalog's previous codes of this region, against which the host
+    counts changed blocks, and the chain they end, or (None, None) when the
+    next frame must be a keyframe."""
     if chain_lookup is None:
         return None, None
     rc = chain_lookup(name, num_parts)
@@ -179,10 +179,11 @@ def snapshot_pytree(tree, step: int = 0, codec: str = "raw",
     """Snapshot a pytree of (possibly sharded) jax.Arrays to host memory.
 
     ``codec="q8"`` / ``"q8-delta"``: float leaves are quantized on device
-    (``kernels/ckpt_codec``) before the D2H copy; ``chain_lookup(name,
-    num_parts)`` supplies the catalog's previous-codes state so ``q8-delta``
+    (``kernels/ckpt_codec.quantize``) before the D2H copy; ``chain_lookup(name,
+    num_parts)`` supplies the catalog's previous codes so ``q8-delta``
     regions ship sparse XOR-delta frames (``ICheckClient.delta_chain_lookup``
-    is the intended callable).  Non-float leaves always travel raw.
+    is the intended callable).  Non-float leaves, and every leaf under any
+    other codec, cross raw (a byte codec such as ``zstd`` runs at commit).
 
     Traced, the ``snapshot`` span nests in the caller's current span, or
     else opens a provisional trace that the commit of this snapshot adopts
@@ -207,7 +208,7 @@ def _snapshot_leaves(flat, codec: str, chain_lookup, impl: Optional[str],
                      ) -> Dict[str, SnapshotRegion]:
     encode = codec in ("q8", "q8-delta")
     if encode:
-        from ..kernels.ckpt_codec import quantize, quantize_delta
+        from ..kernels.ckpt_codec import quantize
 
     # 1) kick all async D2H copies; for encoded leaves, launch the device
     #    quantize first and async-copy its (int8, f32/256) outputs instead
@@ -228,21 +229,10 @@ def _snapshot_leaves(flat, codec: str, chain_lookup, impl: Optional[str],
                 if codec == "q8-delta":
                     prev, parent_chain = _chain_states(
                         chain_lookup, name, desc.num_parts, sizes)
-                outs = {}
-                for p, a in parts.items():
-                    if prev is not None:
-                        prev_q = prev[p].codes_dev
-                        if prev_q is None:
-                            prev_q = prev[p].codes
-                        _, s, q = quantize_delta(a, prev_q, impl=impl)
-                    else:
-                        q, s = quantize(a, impl=impl)
-                    # the new full codes + scales cross D2H (~1/4 of the
-                    # f32 bytes; which blocks changed is counted host-side
-                    # against the previous codes); q also stays
-                    # device-resident for the next commit, so nothing is
-                    # uploaded back
-                    outs[p] = (q, s)
+                # the new full codes + scales cross D2H (~1/4 of the f32
+                # bytes); which blocks changed is counted host-side against
+                # the previous codes, so a delta needs no other kernel
+                outs = {p: quantize(a, impl=impl) for p, a in parts.items()}
                 for q, s in outs.values():
                     for out in (q, s):
                         if hasattr(out, "copy_to_host_async"):
@@ -316,8 +306,6 @@ def _gather_encoded(name: str, leaf, codec: str, w: dict,
                         changed_blocks=packed["changed_blocks"],
                         delta_built=built,
                         delta_discarded=built and frame == "key")
-            for p, (q_dev, _) in w["outs"].items():
-                states[p].codes_dev = q_dev
         else:
             blobs = {p: q8_pack_full(n, codes, scales)
                      for p, (n, codes, scales) in qparts.items()}
@@ -334,6 +322,39 @@ def _gather_encoded(name: str, leaf, codec: str, w: dict,
     return SnapshotRegion(meta=meta, parts={}, boxes=w["boxes"], encoded=enc)
 
 
+def place_leaf(parts: Dict[int, np.ndarray], meta: RegionMeta, dtype=None,
+               sharding=None, tracer: Optional[TraceCollector] = None,
+               trace_id: Optional[str] = None):
+    """One jax.Array rebuilt from its region's parts and placed.
+
+    Each part is written at its box of ``meta.partition`` (a 1-D partition
+    is rejoined along its axis), the whole is cast to ``dtype`` (None: the
+    stored dtype) and ``device_put`` under ``sharding`` (None: the default
+    device).  Traced, a ``restore/assemble`` and a ``restore/h2d`` span
+    (the ``device_put`` call; the transfer itself shows on the device
+    trace) under ``trace_id``."""
+    import jax
+
+    tracer = tracer if tracer is not None else TraceCollector()
+    with tracer.span("restore/assemble", trace_id, TRACK, region=meta.name):
+        if meta.partition.scheme == PartitionScheme.MESH:
+            boxes = meta.partition.bounds
+            full = np.empty(meta.shape, dtype=np.dtype(meta.dtype))
+            for idx, part in parts.items():
+                dsl = tuple(slice(lo, hi) for lo, hi in boxes[idx])
+                full[dsl] = part.reshape([hi - lo for lo, hi in boxes[idx]])
+        else:
+            ordered = [parts[i] for i in range(meta.partition.num_parts)]
+            full = planlib.assemble_array(ordered, meta.partition,
+                                          meta.shape)
+        if dtype is not None:
+            full = full.astype(dtype, copy=False)
+        tracer.note(bytes=full.nbytes)
+    with tracer.span("restore/h2d", trace_id, TRACK, region=meta.name,
+                     bytes=full.nbytes):
+        return jax.device_put(full, sharding)
+
+
 def restore_pytree(template, regions: Dict[str, Dict[int, np.ndarray]],
                    region_meta: Dict[str, RegionMeta],
                    shardings: Optional[Dict[str, Any]] = None,
@@ -345,10 +366,8 @@ def restore_pytree(template, regions: Dict[str, Dict[int, np.ndarray]],
     device layout).  Parts may come from a *different* partitioning than the
     target: they are reassembled via their recorded boxes and re-split by
     ``device_put`` — the caller can instead use ``ICheckClient.redistribute``
-    to move only the needed slices.  Traced, each leaf is a
-    ``restore/assemble`` span and a ``restore/h2d`` span (the ``device_put``
-    call; the transfer itself shows on the device trace), nested in the
-    caller's current span.
+    to move only the needed slices.  Each leaf goes through
+    :func:`place_leaf`; traced, its spans nest in the caller's current span.
     """
     import jax
 
@@ -359,28 +378,8 @@ def restore_pytree(template, regions: Dict[str, Dict[int, np.ndarray]],
     leaves = []
     for path, leaf in flat:
         name = _leaf_name(path)
-        meta = region_meta[name]
-        parts = regions[name]
-        with tracer.span("restore/assemble", trace_id, TRACK, region=name):
-            if meta.partition.scheme == PartitionScheme.MESH:
-                boxes = meta.partition.bounds
-                full = np.empty(meta.shape, dtype=np.dtype(meta.dtype))
-                for idx, part in parts.items():
-                    dsl = tuple(slice(lo, hi) for lo, hi in boxes[idx])
-                    full[dsl] = part.reshape([hi - lo
-                                              for lo, hi in boxes[idx]])
-            else:
-                ordered = [parts[i] for i in range(meta.partition.num_parts)]
-                full = planlib.assemble_array(ordered, meta.partition,
-                                              meta.shape)
-            target_dtype = getattr(leaf, "dtype", full.dtype)
-            full = full.astype(target_dtype, copy=False)
-            tracer.note(bytes=full.nbytes)
-        sharding = (shardings or {}).get(name)
-        with tracer.span("restore/h2d", trace_id, TRACK, region=name,
-                         bytes=full.nbytes):
-            if sharding is not None:
-                leaves.append(jax.device_put(full, sharding))
-            else:
-                leaves.append(jax.device_put(full))
+        leaves.append(place_leaf(regions[name], region_meta[name],
+                                 getattr(leaf, "dtype", None),
+                                 (shardings or {}).get(name), tracer,
+                                 trace_id))
     return jax.tree_util.tree_unflatten(treedef, leaves)

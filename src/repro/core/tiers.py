@@ -14,8 +14,8 @@ so new levels can be added without touching the controller:
 
 Every tier does crc32 + capacity accounting.  A per-node
 :class:`TierPipeline` owns shard placement across its tiers (spill on
-capacity pressure, promotion back to RAM on read) and is a drop-in for the
-old ``MemoryStore`` mapping interface.
+capacity pressure, promotion back to RAM on read) behind one mapping
+interface.
 
 The pipeline also owns the *codec path*: ``encode_payload`` /
 ``decode_payload`` thread the ``zstd``, ``q8`` and ``q8-delta`` codecs
@@ -96,17 +96,15 @@ Q8_EMPTY_DELTA_NBYTES = 1 + 8 + 4
 
 @dataclasses.dataclass
 class DeltaState:
-    """Previous-codes handle for one region part (owned by the catalog)."""
+    """Previous-codes handle for one region part (owned by the catalog).
+
+    Host arrays only: the next save's changed-block count and XOR rows read
+    them on the host, so no copy of a region's codes stays on the device
+    between saves."""
 
     n: int                    # unpadded element count
     codes: np.ndarray         # (nb, BLOCK) int8
     scales: np.ndarray        # (nb, 1) f32
-    # device-resident copy of ``codes`` (a jax.Array), attached by the
-    # device-encode path so the next ``quantize_delta`` reads the previous
-    # codes in place instead of re-uploading them H2D every commit; costs
-    # 1/4 of the region's f32 bytes in device memory, dropped on chain
-    # reset.  None on the pure-host path.
-    codes_dev: object = None
 
 
 def zstd_available() -> bool:
@@ -1537,12 +1535,11 @@ class RemoteObjectTier:
 class TierPipeline:
     """Ordered storage tiers of one iCheck node, fastest first.
 
-    Drop-in for the old single-level ``MemoryStore``: puts land in the
-    fastest tier with room (spilling down on :class:`CapacityError`), reads
-    search top-down and promote a hit back into the fastest tier when it
-    fits.  With a single :class:`MemoryTier` this degenerates to exactly the
-    old behaviour, including raising ``CapacityError`` when full — which is
-    what lets the controller escalate to the RM for more nodes (§III-A).
+    Puts land in the fastest tier with room (spilling down on
+    :class:`CapacityError`), reads search top-down and promote a hit back
+    into the fastest tier when it fits.  With a single :class:`MemoryTier`
+    this is one in-memory store, raising ``CapacityError`` when full — which
+    is what lets the controller escalate to the RM for more nodes (§III-A).
     """
 
     def __init__(self, tiers: Sequence[StorageTier], bus=None,
@@ -1553,10 +1550,9 @@ class TierPipeline:
         self.bus = bus
         self.node_id = node_id
         # compound operations (spill on put, promote on get) span tiers;
-        # this lock makes them atomic w.r.t. each other, like the single
-        # MemoryStore lock they replace (tier-internal locks are not enough:
-        # a concurrent reader could observe a shard mid-promotion as absent
-        # from both tiers)
+        # this lock makes them atomic w.r.t. each other (tier-internal
+        # locks are not enough: a concurrent reader could observe a shard
+        # mid-promotion as absent from both tiers)
         self._lock = threading.RLock()
 
     # -- capacity accounting (aggregated) ----------------------------------
@@ -1576,7 +1572,7 @@ class TierPipeline:
         if self.bus is not None:
             self.bus.publish(name, **kw)
 
-    # -- mapping interface (MemoryStore-compatible) ------------------------
+    # -- mapping interface -------------------------------------------------
     def put(self, key: ShardKey, payload: bytes, crc: Optional[int] = None) -> None:
         # events are published only after the pipeline lock is released:
         # a subscriber (e.g. the lifecycle service's watermark check) may

@@ -1,19 +1,21 @@
 """Public, jit-friendly checkpoint-codec ops (pad/flatten + impl dispatch).
 
-``repro.core.snapshot.snapshot_pytree(codec="q8"|"q8-delta")`` calls these
-on the commit hot path: every float region part goes through
-:func:`quantize` (or :func:`quantize_delta` against the catalog's
-previous-codes state) *on device*, before the D2H copy, so the host — and
-then the client→agent fabric and every storage tier — moves int8 codes +
-1/256 overhead of f32 scales instead of the raw f32 payload (~4x fewer
-bytes; for ``q8-delta`` the host packs the XOR deltas into sparse frames
-where unchanged blocks cost zero wire bytes).  Shape/dtype restoration
-metadata and the delta-frame bookkeeping travel in ``RegionMeta``
-(``dtype``, ``partition``, plus ``frame``/``chain`` on the per-checkpoint
-copies); :func:`undelta_dequantize` is the device-side replay primitive
-that folds a delta frame back onto the previous codes — the host restart
-path (``repro.core.tiers.q8_chain_decode``) applies the same XOR +
-dequantize in numpy, asserted bit-identical in the test suite.
+``repro.core.snapshot.snapshot_pytree(codec="q8"|"q8-delta")`` calls one
+of these on the commit hot path: every float region part goes through
+:func:`quantize` *on device*, before the D2H copy, so the host — and then
+the client→agent fabric and every storage tier — moves int8 codes + 1/256
+overhead of f32 scales instead of the raw f32 payload (~4x fewer bytes;
+for ``q8-delta`` the host counts the changed blocks against the catalog's
+previous codes and XORs only those into sparse frames, where unchanged
+blocks cost zero wire bytes).  Shape/dtype restoration metadata and the
+delta-frame bookkeeping travel in ``RegionMeta`` (``dtype``,
+``partition``, plus ``frame``/``chain`` on the per-checkpoint copies).
+:func:`quantize_delta` (codes and their XOR against previous codes in one
+pass) and :func:`undelta_dequantize` (the device-side replay primitive
+that folds a delta frame back onto the previous codes) are not on the save
+or restore path; the host restart path
+(``repro.core.tiers.q8_chain_decode``) applies the same XOR + dequantize
+in numpy, asserted bit-identical in the test suite.
 """
 from __future__ import annotations
 

@@ -25,8 +25,9 @@ mesh's shardings.
 """
 from __future__ import annotations
 
+import dataclasses
 import time
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import numpy as np
@@ -170,12 +171,15 @@ class ElasticTrainer:
     def _batch_sharding(self):
         return NamedSharding(self.mesh, PartitionSpec("data"))
 
+    def _state_sharding(self, mesh: Mesh) -> NamedSharding:
+        """Where every TrainState leaf lives on ``mesh``: replicated over
+        "data" (each data-parallel rank holds the whole state)."""
+        return NamedSharding(mesh, PartitionSpec())
+
     def _shard_state(self):
-        """(Re)commit the TrainState onto the current mesh (DP-replicated
-        params; batch over "data")."""
-        rep = NamedSharding(self.mesh, PartitionSpec())
-        self.state = jax.tree.map(lambda x: jax.device_put(x, rep),
-                                  self.state)
+        """Commit the TrainState onto the current mesh."""
+        self.state = jax.device_put(self.state,
+                                    self._state_sharding(self.mesh))
 
     def _jit_step(self):
         step_fn = make_train_step(self.cfg, self.opt_cfg, self.schedule,
@@ -201,25 +205,17 @@ class ElasticTrainer:
     def commit(self, blocking: bool = False):
         """icheck_commit: async snapshot -> agents (paper line 26).
 
-        With a q8 codec the snapshot quantizes on device (q8-delta: XOR
+        With a q8 codec the snapshot quantizes on device (q8-delta: framed
         against the catalog's previous codes) before the D2H copy, and
-        ``commit_snapshot`` ships those frames as-is."""
-        step = int(self.state.step)
-        data_parts = {DATA_REGION: {0: self.data.state_array()}}
-        if self.client.codec in ("q8", "q8-delta"):
-            snap = snapshot_pytree(self.state, step=step,
-                                   codec=self.client.codec,
-                                   chain_lookup=self.client.delta_chain_lookup,
-                                   impl=self.impl, tracer=self.tracer)
-            h = self.client.commit_snapshot(snap, extra_parts=data_parts,
-                                            blocking=blocking)
-        else:
-            snap = snapshot_pytree(self.state, step=step, tracer=self.tracer)
-            self.client.add_adapt_snapshot(snap)   # refresh region boxes
-            parts = {name: r.parts for name, r in snap.regions.items()}
-            parts.update(data_parts)
-            h = self.client.commit(step, parts, blocking=blocking,
-                                   snapshot_trace=snap.trace)
+        ``commit_snapshot`` ships those frames as-is; under any other codec
+        it ships the raw parts, which the commit encodes."""
+        snap = snapshot_pytree(self.state, step=int(self.state.step),
+                               codec=self.client.codec,
+                               chain_lookup=self.client.delta_chain_lookup,
+                               impl=self.impl, tracer=self.tracer)
+        h = self.client.commit_snapshot(
+            snap, extra_parts={DATA_REGION: {0: self.data.state_array()}},
+            blocking=blocking)
         self._pending_commits.append(h)
         self._last_commit_t = self._clock.now()
         return h
@@ -236,64 +232,63 @@ class ElasticTrainer:
                                                meta.ckpt_id), self._track):
                 data_parts = regions.pop(DATA_REGION)
                 self.data.restore(data_parts[0])
-                template = jax.tree.map(
-                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                    self.state)
                 region_meta = {name: meta.regions[name] for name in regions}
-                self.state = restore_pytree(template, regions, region_meta,
-                                            tracer=self.tracer)
-                self._shard_state()
+                # the live state is the template: its leaves give the
+                # structure and dtypes, and are replaced whole
+                self.state = restore_pytree(
+                    self.state, regions, region_meta,
+                    shardings=dict.fromkeys(
+                        regions, self._state_sharding(self.mesh)),
+                    tracer=self.tracer)
         return True
 
     # ---------------------------------------------------------------- resize
-    def _redistribute(self, new_ranks: int):
-        """Agent-side slice redistribution onto the new mesh (paper SSIII-B).
-
-        Requires a checkpoint: commit (blocking) first, then pull only the
-        slices each new part needs from the agents.
-        """
+    def _adapt_targets(self, new_ranks: int
+                       ) -> Tuple[Mesh, Dict[str, Tuple[planlib.Box, ...]]]:
+        """Both resize paths start here: commit (blocking) the checkpoint
+        the agents redistribute from, then give the new mesh and each
+        leaf's boxes under the state's sharding on it."""
         self.commit(blocking=True)
         new_mesh = self.make_mesh(new_ranks)
-        template = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.state)
-        names = leaf_names(self.state)
-        flat, treedef = jax.tree_util.tree_flatten(template)
-        rep = NamedSharding(new_mesh, PartitionSpec())
-        new_leaves = []
-        for name, leaf in zip(names, flat):
-            boxes = planlib.mesh_part_bounds(leaf.shape, rep)
-            parts = self.client.redistribute_mesh(name, boxes)
-            full = np.zeros(leaf.shape, leaf.dtype)
-            for idx, arr in parts.items():
-                sl = tuple(slice(lo, hi) for lo, hi in boxes[idx])
-                full[sl] = arr
-            new_leaves.append(jax.device_put(full, rep))
+        sharding = self._state_sharding(new_mesh)
+        boxes = {name: planlib.mesh_part_bounds(leaf.shape, sharding)
+                 for name, leaf in zip(leaf_names(self.state),
+                                       jax.tree.leaves(self.state))}
+        return new_mesh, boxes
+
+    def _place_state(self, new_mesh: Mesh, boxes: Dict[str, tuple],
+                     parts: Dict[str, dict]) -> None:
+        """Rebuild the TrainState on ``new_mesh`` from each leaf's
+        redistributed parts at its new ``boxes``."""
+        metas = {}
+        for name, b in boxes.items():
+            meta = self.client.regions[name]
+            metas[name] = dataclasses.replace(
+                meta, partition=planlib.boxes_to_desc(meta.shape, b))
+        self.state = restore_pytree(
+            self.state, parts, metas,
+            shardings=dict.fromkeys(boxes, self._state_sharding(new_mesh)),
+            tracer=self.tracer)
         self.mesh = new_mesh
-        self.state = jax.tree_util.tree_unflatten(treedef, new_leaves)
+
+    def _redistribute(self, new_ranks: int):
+        """Agent-side slice redistribution onto the new mesh (paper SSIII-B):
+        pull only the slices each new part needs from the agents."""
+        new_mesh, boxes = self._adapt_targets(new_ranks)
+        self._place_state(new_mesh, boxes, {
+            name: self.client.redistribute_mesh(name, b)
+            for name, b in boxes.items()})
 
     def _begin_overlap_adapt(self, new_ranks: int) -> None:
         """Phase 1: commit a base checkpoint, then open one overlap window
         per TrainState leaf targeting the new mesh's boxes.  The RM's resize
         event stays pending (``adapt_begin`` re-probes it at cutover), so
         training continues on the old ranks while the streams run."""
-        self.commit(blocking=True)
-        new_mesh = self.make_mesh(new_ranks)
-        template = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.state)
-        names = leaf_names(self.state)
-        flat, treedef = jax.tree_util.tree_flatten(template)
-        rep = NamedSharding(new_mesh, PartitionSpec())
-        handles: Dict[str, object] = {}
-        boxes_by_name: Dict[str, tuple] = {}
-        for name, leaf in zip(names, flat):
-            boxes = planlib.mesh_part_bounds(leaf.shape, rep)
-            boxes_by_name[name] = boxes
-            handles[name] = self.client.redistribute_mesh(name, boxes,
-                                                          overlap=True)
-        self._adapt_handles = handles
-        self._adapt_ctx = {"new_ranks": new_ranks, "new_mesh": new_mesh,
-                           "boxes": boxes_by_name, "treedef": treedef,
-                           "names": names, "flat": flat}
+        new_mesh, boxes = self._adapt_targets(new_ranks)
+        self._adapt_handles = {
+            name: self.client.redistribute_mesh(name, b, overlap=True)
+            for name, b in boxes.items()}
+        self._adapt_ctx = {"new_mesh": new_mesh, "boxes": boxes}
 
     def _finish_overlap_adapt(self) -> None:
         """Phase 2: quiesce (one last delta commit — the only frames the
@@ -302,19 +297,8 @@ class ElasticTrainer:
         ctx = self._adapt_ctx
         window = self.app.adapt_begin()
         self.commit(blocking=True)
-        new_mesh = ctx["new_mesh"]
-        rep = NamedSharding(new_mesh, PartitionSpec())
-        new_leaves = []
-        for name, leaf in zip(ctx["names"], ctx["flat"]):
-            boxes = ctx["boxes"][name]
-            parts = self._adapt_handles[name].cutover()
-            full = np.zeros(leaf.shape, leaf.dtype)
-            for idx, arr in parts.items():
-                sl = tuple(slice(lo, hi) for lo, hi in boxes[idx])
-                full[sl] = arr
-            new_leaves.append(jax.device_put(full, rep))
-        self.mesh = new_mesh
-        self.state = jax.tree_util.tree_unflatten(ctx["treedef"], new_leaves)
+        self._place_state(ctx["new_mesh"], ctx["boxes"], {
+            name: h.cutover() for name, h in self._adapt_handles.items()})
         self.app.adapt_commit()
         self.client.ranks = window.new_ranks
         self._jit_step()

@@ -1,5 +1,7 @@
 """Incremental (q8-delta) checkpointing: wire codec, chain lifecycle,
 dtype sweep, device-side snapshot encode, and telemetry gauges."""
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -605,6 +607,39 @@ def test_device_snapshot_delta_commit_and_restart(cluster):
     prom = cluster.telemetry.prometheus()
     assert "icheck_codec_compression_ratio" in prom
     assert "icheck_codec_encode_seconds" in prom
+    client.finalize()
+
+
+def test_delta_save_encodes_with_quantize_alone(cluster, monkeypatch):
+    """A q8-delta save against a live chain runs one device kernel,
+    ``quantize``: the changed blocks are counted on the host against the
+    catalog's previous codes, which stay on the host only."""
+    jax = pytest.importorskip("jax")
+    jnp = jax.numpy
+    from repro.core import snapshot_pytree
+    from repro.kernels import ckpt_codec
+
+    def refuse(*a, **k):
+        raise AssertionError("the save called quantize_delta")
+
+    monkeypatch.setattr(ckpt_codec, "quantize_delta", refuse)
+    client = ICheckClient("app", cluster.controller, ranks=1,
+                          codec="q8-delta").init()
+    rng = np.random.default_rng(17)
+    tree = {"w": jnp.asarray(rng.standard_normal(1000).astype(np.float32))}
+    for step in range(2):
+        snap = snapshot_pytree(tree, step=step, codec="q8-delta",
+                               chain_lookup=client.delta_chain_lookup)
+        h = client.commit_snapshot(snap, blocking=True, drain=False)
+        tree["w"] = tree["w"].at[:4].add(1.0)
+    assert snap.regions["w"].encoded.frame == "delta"
+    assert h.meta.regions["w"].frame == "delta"
+    assert h.meta.regions["w"].chain == (0, 1)
+    states = client.delta_chain_lookup("w", 1).parts
+    assert states
+    for st in states.values():
+        for f in dataclasses.fields(st):
+            assert not isinstance(getattr(st, f.name), jax.Array), f.name
     client.finalize()
 
 

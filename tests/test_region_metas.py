@@ -5,7 +5,8 @@ sharding; it must equal, field for field, the region a raw
 ``snapshot_pytree`` of the same state builds after its D2H copy.
 ``ElasticTrainer`` registers from those metas, and a checkpoint chain it
 starts must still restore: bit for bit with the raw codec, within half a
-block scale with q8-delta.
+block scale with q8-delta.  The way back, ``place_leaf``, rebuilds a leaf
+from its parts' boxes under a target sharding.
 """
 import subprocess
 import sys
@@ -18,7 +19,9 @@ import pytest
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.core import ICheckCluster, region_metas, snapshot_pytree
-from repro.core.snapshot import leaf_names
+from repro.core import plan as planlib
+from repro.core.snapshot import leaf_names, place_leaf
+from repro.core.types import RegionMeta
 from repro.kernels.ckpt_codec.blocks import BLOCK
 from repro.optim import AdamWConfig
 from repro.train import ElasticTrainer
@@ -57,6 +60,28 @@ def test_region_metas_equal_raw_snapshot_metas(arch, leaves):
     assert set(leaves) <= set(got)
     assert got["step"].shape == () and got["step"].dtype == "int32"
     assert got["step"].nbytes == 4
+
+
+@pytest.mark.parametrize("boxes", [
+    (((0, 6), (0, 8)),),
+    (((0, 2), (0, 8)), ((2, 6), (0, 8))),
+    (((0, 6), (0, 5)), ((0, 6), (5, 8))),
+], ids=["one-box", "rows-in-two-boxes", "columns-in-two-boxes"])
+def test_place_leaf_rebuilds_boxed_parts(boxes):
+    """Parts cut at their boxes come back as the whole leaf, cast to the
+    target dtype, on the replicated sharding asked for."""
+    x = np.arange(48, dtype=np.float32).reshape(6, 8) / 7
+    parts = {i: x[tuple(slice(lo, hi) for lo, hi in box)].ravel()
+             for i, box in enumerate(boxes)}
+    meta = RegionMeta(name="w", shape=x.shape, dtype="float32",
+                      partition=planlib.boxes_to_desc(x.shape, boxes),
+                      nbytes=x.nbytes)
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("data",))
+    rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    got = place_leaf(parts, meta, jax.numpy.bfloat16, rep)
+    assert got.sharding == rep and got.dtype == jax.numpy.bfloat16
+    np.testing.assert_array_equal(np.asarray(got),
+                                  x.astype(jax.numpy.bfloat16))
 
 
 MESH_SCRIPT = r"""
